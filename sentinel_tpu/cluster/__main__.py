@@ -204,6 +204,9 @@ def main(argv=None) -> int:
                    help="shared window-checkpoint path for HA warm starts "
                         "(default: csp.sentinel.cluster.ha.checkpoint.path)")
     args = p.parse_args(argv)
+    from sentinel_tpu.utils import compile_cache
+
+    compile_cache.enable()
 
     if args.cluster_map:
         from sentinel_tpu.cluster.ha import default_machine_id

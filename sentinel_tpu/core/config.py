@@ -350,7 +350,7 @@ DEFAULT_OVERLOAD_RLS_MAX_CONCURRENT = 64
 DEFAULT_OVERLOAD_CLIENT_BACKOFF_MS = 250
 # Pipeline defaults. Depth 2 = classic double buffering: stage N+1 and
 # harvest N-1 while N computes; deeper only helps when the device step
-# is much longer than host staging (remote-tunnel TPU). 100µs linger
+# is much longer than host staging. 100µs linger
 # matches the historical collector default.
 DEFAULT_PIPELINE_INFLIGHT_DEPTH = 2
 DEFAULT_PIPELINE_LINGER_US = 100

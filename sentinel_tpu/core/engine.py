@@ -40,7 +40,7 @@ from sentinel_tpu.core.exceptions import BlockException, exception_for_reason
 
 
 class DeviceDispatchError(RuntimeError):
-    """A device dispatch died (backend/tunnel failure) AFTER the input
+    """A device dispatch died (backend failure) AFTER the input
     state may have been donated. The raising site has already dropped the
     engine to a cold state (reference restart stance: rules durable,
     stats ephemeral); catchers decide their own degradation — the sync
@@ -2053,7 +2053,7 @@ class SentinelEngine:
                     buf[k][0] = v
             try:
                 dec = self._run_entry_batch_locked(EntryBatch(**buf))
-            except DeviceDispatchError as ex:  # backend/tunnel death only
+            except DeviceDispatchError as ex:  # backend death only
                 self._note_fail_open(str(ex))
                 return 0, 0  # fail open, like fallbackToLocalOrPass
             return int(dec.reason[0]), int(dec.wait_us[0])
@@ -2108,7 +2108,7 @@ class SentinelEngine:
         fails its tickets open and the next dispatch rebuilds."""
         try:
             return np.asarray(dec.reason), np.asarray(dec.wait_us)
-        except Exception as ex:  # noqa: BLE001 — backend/tunnel death
+        except Exception as ex:  # noqa: BLE001 — backend death
             with self._lock:
                 self._state = None
             raise DeviceDispatchError(

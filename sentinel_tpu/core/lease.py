@@ -1,7 +1,7 @@
 """Token-lease fast path: host-side admission for simple hot resources.
 
-SURVEY.md §7 hard part #1: a synchronous device dispatch costs ~10-100µs
-(65ms+ through a remote tunnel), which no per-request path can hide. For
+SURVEY.md §7 hard part #1: a synchronous device dispatch costs ~10-100µs,
+which no per-request path can hide. For
 the dominant traffic classes, admission arithmetic is a handful of
 integer/float ops, so the host runs it directly against mirrored state
 ("the quota is leased from the device view") and streams the decided

@@ -368,8 +368,8 @@ class Pipeline:
         try:
             reasons, waits = self.engine.harvest_decisions(rec.dec)
         except Exception:
-            # The async compute died after dispatch (backend/tunnel
-            # failure surfacing at materialization): fail this cycle's
+            # The async compute died after dispatch (backend failure
+            # surfacing at materialization): fail this cycle's
             # tickets open — the engine has already dropped to a cold
             # state, and the next dispatch recovers. Buffers are NOT
             # recycled (the failed stream may still reference them);

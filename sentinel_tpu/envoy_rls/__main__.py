@@ -48,6 +48,9 @@ def main() -> None:
     ap.add_argument("--rules",
                     default=os.environ.get("SENTINEL_RLS_RULES", ""))
     args = ap.parse_args()
+    from sentinel_tpu.utils import compile_cache
+
+    compile_cache.enable()
 
     manager = EnvoyRlsRuleManager()
     service = SentinelEnvoyRlsService(manager)

@@ -158,7 +158,10 @@ def _eval_system(
     # cumsum over inbound survivors.
     contrib = jnp.where(survivors & batch.entry_in, batch.count, 0)
     tok_prefix = jnp.cumsum(contrib) - contrib
-    ent_contrib = jnp.where(survivors & batch.entry_in, 1, 0)
+    # int32, not the x64 default of a python-int where: an int64 cumsum
+    # lowers on TPU to a u32-pair reduce-window that overflows scoped VMEM
+    # inside the pod step (tests/test_tpu_compile.py).
+    ent_contrib = (survivors & batch.entry_in).astype(jnp.int32)
     ent_prefix = jnp.cumsum(ent_contrib) - ent_contrib
 
     # Per-second normalization of window sums (reference passQps divides by

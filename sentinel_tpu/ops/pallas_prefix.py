@@ -5,9 +5,9 @@ row blocks, generating each [block, N] comparison mask on the VPU and
 contracting it on the MXU — with the mask and value operands bouncing
 through HBM between scan steps. This kernel keeps everything in VMEM:
 one grid step per row block, the mask generated tile-by-tile and fed
-straight to the MXU, the accumulator never leaving the core. Measured
-on the real chip at bench shapes (N=8192, M=2, 16-step scan):
-0.303 ms/step vs 0.518 for the XLA scan — 1.71x.
+straight to the MXU, the accumulator never leaving the core. An earlier
+round timed it at 1.71x the XLA scan at bench shapes (N=8192, M=2); it
+is not measured on the chip at HEAD (ROADMAP A2).
 
 Exactness: the mask is {0,1} f32 and values are f32, so results are
 exact for integer counts < 2^24 — strictly wider than the XLA path's
@@ -30,7 +30,6 @@ from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64 as _enable_x64
 from jax.experimental import pallas as pl
 
 _BLOCK = 512   # rows per grid step
@@ -76,9 +75,7 @@ def prefix_pallas(ids: jnp.ndarray, values: jnp.ndarray,
     n = ids.shape[0]
     npad = -(-n // _BLOCK) * _BLOCK
     squeeze, m, ids32, vals1 = prep_prefix_pair(ids, values, npad)
-    # jax.enable_x64 was removed in jax 0.4.37; the experimental context
-    # manager is the surviving spelling of the same switch.
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             _make_kernel(npad, m + 1),
             grid=(npad // _BLOCK,),

@@ -39,10 +39,7 @@ from sentinel_tpu.core.batch import Decisions, EntryBatch, ExitBatch
 from sentinel_tpu.ops import step as S
 from sentinel_tpu.ops import window as W
 
-try:  # jax >= 0.4.35 exposes shard_map at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -238,17 +235,17 @@ def make_pod_steps(mesh: Mesh, axis: str = AXIS, cluster_param: bool = True,
         in_specs=(P(axis), P(), P(axis), P()),
         out_specs=(P(axis), P(axis)),
         # The r5 survivor-fixpoint (ops/fixpoint.py) is a lax.while_loop;
-        # jax's shard_map replication checker has no while rule yet
+        # shard_map's varying-manual-axes checker has no rule for it
         # (mixed-acquire batches crashed with "No replication rule for
-        # while"), so the static rep check is off. Collective correctness
+        # while"), so the static check is off. Collective correctness
         # is unaffected — psums are explicit in the step body.
-        check_rep=False,
+        check_vma=False,
     )
     exit_ = _shard_map(
         functools.partial(_pod_exit, axis=axis, shadow_rules=shadow_rules),
         mesh=mesh,
         in_specs=(P(axis), P(), P(axis), P()),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     return entry, exit_

@@ -191,14 +191,14 @@ def make_dcn_pod_steps(mesh: Mesh, cluster_param: bool = True,
         out_specs=(P(DCN_AXIS, ICI_AXIS), P((DCN_AXIS, ICI_AXIS))),
         # No shard_map replication rule for the fixpoint while_loop —
         # see make_pod_steps (parallel/cluster.py) for the rationale.
-        check_rep=False,
+        check_vma=False,
     )
     exit_ = _shard_map(
         _dcn_exit,
         mesh=mesh,
         in_specs=(P(DCN_AXIS, ICI_AXIS), P(), P((DCN_AXIS, ICI_AXIS)), P()),
         out_specs=P(DCN_AXIS, ICI_AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     return entry, exit_
 

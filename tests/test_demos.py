@@ -7,8 +7,7 @@ dashboard demo serves forever by design and is exercised through
 ``tests/test_dashboard.py`` instead.
 
 Each subprocess clears PYTHONPATH (the demos' ``_demo_env`` puts the
-repo root on sys.path themselves), which also keeps the smoke tests
-alive when a host accelerator plugin is unreachable.
+repo root on sys.path themselves).
 """
 
 import os

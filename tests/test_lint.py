@@ -28,7 +28,7 @@ def test_compileall_package():
 
 
 def test_compile_driver_entry_points():
-    for name in ("__graft_entry__.py", "bench.py"):
+    for name in ("__graft_entry__.py", "bench.py", "chip_smoke.py"):
         py_compile.compile(str(REPO / name), doraise=True)
 
 

@@ -178,12 +178,22 @@ def test_empty_family_compiles_zero_slots_with_ratchet_floor():
     assert P.compile_param_rules([], reg, 64, min_slots=1).slots == 1
 
 
+def _device_path(engine):
+    """Single-param rules are lease-eligible (core/lease.py), so these
+    compile-ratchet tests turn the lease off to pin the device path they
+    measure (with it on they passed only when an earlier test in the same
+    worker had left a device dispatch behind)."""
+    engine.lease_enabled = False
+    engine._rebuild_leases()
+
+
 def test_engine_slot_floor_ratchets_across_pushes(engine, frozen_time):
     """Pushing a family's first rule widens its slot floor permanently:
     clearing the rules later compiles the SAME tensor shape, so the
     fused step is not retraced by the push cycle (the round-4
     'rule pushes don't recompile' guarantee, kept under zero-slot
     compiles of empty families)."""
+    _device_path(engine)
     assert engine._slot_floor["param"] == 0
     st.load_param_flow_rules([st.ParamFlowRule("hot", param_idx=0, count=2)])
     h = st.entry_ok("hot", args=("k",))  # forces compile + dispatch
@@ -204,6 +214,7 @@ def test_reset_slot_floor_shrinks_after_transient_burst(engine, frozen_time):
     widens a family's loop, ``reset_slot_floor()`` (the ``resetSlotFloor``
     ops command) shrinks the compiled shapes back to what current rules
     need, at the documented cost of one retrace."""
+    _device_path(engine)
     st.load_param_flow_rules([
         st.ParamFlowRule("hot", param_idx=0, count=2, duration_in_sec=i + 1)
         for i in range(4)  # 4 rules on ONE resource -> 4 slots
@@ -275,6 +286,7 @@ def test_rule_push_cycle_never_retraces_after_first_use(engine, frozen_time):
     first use is compiled, pushing new rule VALUES, clearing the family,
     and re-pushing must all hit the same jit specialization — the
     entry jit's trace-cache size stays at 1."""
+    _device_path(engine)
     st.load_flow_rules([st.FlowRule(resource="api", count=100)])
     st.load_param_flow_rules([st.ParamFlowRule("api", param_idx=0, count=50)])
     h = st.entry_ok("api", args=("k",))

@@ -112,8 +112,7 @@ def test_fail_open_is_counted_and_logged(piped, frozen_time, caplog):
 
 
 def test_sync_device_failure_fails_open_and_recovers(engine, frozen_time):
-    """Backend/tunnel death on the SYNC dispatch path (the round-4 outage
-    class): entry() must fail OPEN (counted + logged) like the
+    """Backend death on the SYNC dispatch path: entry() must fail OPEN (counted + logged) like the
     reference's fallbackToLocalOrPass — never surface an XLA error to the
     caller — and the engine must recover with cold stats on the next
     successful dispatch."""
@@ -126,7 +125,7 @@ def test_sync_device_failure_fails_open_and_recovers(engine, frozen_time):
     healthy_jit = engine._entry_jit
 
     def dying_jit(*a, **kw):
-        raise RuntimeError("tunnel died mid-dispatch")
+        raise RuntimeError("backend died mid-dispatch")
 
     engine._entry_jit = dying_jit
     before = engine.fail_open_count
@@ -143,6 +142,22 @@ def test_sync_device_failure_fails_open_and_recovers(engine, frozen_time):
     assert snap["passQps"] >= 1         # stats flowing again
 
 
+def test_warmup_raises_when_the_step_fails_to_compile(engine, frozen_time):
+    """Boot order is load rules, warmup(), serve: a step the compiler
+    refuses must stop the boot with the error, not be swallowed into the
+    fail-open channel that entries use."""
+    st.load_flow_rules([st.FlowRule(resource="w", count=1)])
+    engine._ensure_compiled()
+
+    def refused(*a, **kw):
+        raise RuntimeError("RESOURCE_EXHAUSTED: scoped vmem limit exceeded")
+
+    engine._entry_jit = refused
+    with pytest.raises(st.DeviceDispatchError, match="RESOURCE_EXHAUSTED"):
+        engine.warmup((1,))
+    assert engine.fail_open_count == 0
+
+
 def test_exit_device_failure_never_breaks_caller(engine, frozen_time):
     st.load_flow_rules([st.FlowRule(resource="dx", count=5,
                                     control_behavior=C.CONTROL_BEHAVIOR_RATE_LIMITER,
@@ -151,7 +166,7 @@ def test_exit_device_failure_never_breaks_caller(engine, frozen_time):
     assert h
 
     def dying_jit(*a, **kw):
-        raise RuntimeError("tunnel died on exit")
+        raise RuntimeError("backend died on exit")
 
     engine._exit_jit = dying_jit
     h.exit()                            # must not raise
