@@ -27,6 +27,8 @@ from sentinel_tpu.cluster.constants import (
     TokenResultStatus,
 )
 from sentinel_tpu.cluster.token_service import TokenResult
+from sentinel_tpu.metrics import profiling as prof
+from sentinel_tpu.metrics.profiling import span
 from sentinel_tpu.resilience import HealthGate, RetryPolicy, faults
 
 
@@ -210,22 +212,24 @@ class ClusterTokenClient:
                     continue
                 if not data:
                     break
-                for body in reader.feed(data):
-                    try:
-                        resp = codec.decode_response(body)
-                    except (ValueError, struct.error, IndexError):
-                        # Garbage frame: the length-prefixed stream is
-                        # desynced beyond repair — drop the connection
-                        # (pending requests fail fast, the reconnector
-                        # dials fresh) instead of letting the decode
-                        # error kill this thread with the socket open
-                        # and every future request left to time out.
-                        raise _GarbageFrame()
-                    with self._lock:
-                        entry = self._pending.pop(resp.xid, None)
-                    if entry is not None:
-                        entry[1]["resp"] = resp
-                        entry[0].set()
+                with span(prof.CLIENT_RECEIVE):
+                    for body in reader.feed(data):
+                        try:
+                            resp = codec.decode_response(body)
+                        except (ValueError, struct.error, IndexError):
+                            # Garbage frame: the length-prefixed stream
+                            # is desynced beyond repair — drop the
+                            # connection (pending requests fail fast,
+                            # the reconnector dials fresh) instead of
+                            # letting the decode error kill this thread
+                            # with the socket open and every future
+                            # request left to time out.
+                            raise _GarbageFrame()
+                        with self._lock:
+                            entry = self._pending.pop(resp.xid, None)
+                        if entry is not None:
+                            entry[1]["resp"] = resp
+                            entry[0].set()
         except (OSError, _GarbageFrame):
             pass
         finally:
@@ -250,19 +254,20 @@ class ClusterTokenClient:
             if sock is None:
                 return None
             self._pending[xid] = (done, box)
-        try:
-            raw = codec.encode_request(xid, msg_type, entity)
-        except (ValueError, struct.error):  # oversized frame: fail this call
-            with self._lock:
-                self._pending.pop(xid, None)
-            return None
-        try:
-            faults.fire("cluster.client.send")
-            with self._send_lock:  # frames must not interleave on the wire
-                sock.sendall(raw)
-        except OSError:
-            self._drop_connection()
-            return None
+        with span(prof.CLIENT_SEND):
+            try:
+                raw = codec.encode_request(xid, msg_type, entity)
+            except (ValueError, struct.error):  # oversized frame: fail it
+                with self._lock:
+                    self._pending.pop(xid, None)
+                return None
+            try:
+                faults.fire("cluster.client.send")
+                with self._send_lock:  # frames must not interleave
+                    sock.sendall(raw)
+            except OSError:
+                self._drop_connection()
+                return None
         wait_s = self.request_timeout_s if timeout_s is None \
             else min(timeout_s, self.request_timeout_s)
         if not done.wait(wait_s):
@@ -414,7 +419,8 @@ class ClusterTokenClient:
                 boxes.append(box)
         try:
             faults.fire("cluster.client.send")
-            with self._send_lock:  # frames must not interleave on the wire
+            # Frames must not interleave on the wire.
+            with span(prof.CLIENT_SEND), self._send_lock:
                 sock.sendall(b"".join(frames))
         except OSError:
             self._drop_connection()  # sets the gather for every pending xid

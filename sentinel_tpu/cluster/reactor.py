@@ -56,6 +56,8 @@ from sentinel_tpu.cluster.server import (
     mutate_reply,
     process_control_frame,
 )
+from sentinel_tpu.metrics import profiling as prof
+from sentinel_tpu.metrics.profiling import span
 from sentinel_tpu.resilience import faults
 _LISTEN_BACKLOG = 256  # the legacy frontend's reconnect-storm headroom
 
@@ -231,13 +233,15 @@ class WireReactor:
                     else:
                         conn = kind
                         if mask & selectors.EVENT_READ:
-                            self._read(conn)
+                            with span(prof.REACTOR_READ):
+                                self._read(conn)
                         if mask & selectors.EVENT_WRITE and not conn.closed:
                             self._try_send(conn)
                 # Coalesce: everything staged this cycle goes out as
                 # fused-step group(s) through the bounded batcher.
                 if self._staged:
-                    self._submit_staged()
+                    with span(prof.REACTOR_SUBMIT):
+                        self._submit_staged()
                 # Flush connections whose slots got filled off-loop.
                 if self._dirty:
                     with self._dirty_lock:
@@ -457,38 +461,39 @@ class WireReactor:
         """Fill every routed reply slot from a completed (or failed)
         group; runs on the harvester thread or, for pre-set groups,
         inline on the reactor thread."""
-        results = box.get("results")
-        shed_retry = box.get("shed_retry_after_ms")
-        t_done = time.perf_counter()
-        # Waterfall stamps (ISSUE 18): admitted groups carry the
-        # batcher's drain/dispatch/device marks; together with the
-        # reactor-side marks they chain gap-free into the 8-stage
-        # record _flush observes. Sheds/fails carry no stamps.
-        wf_stamps = box.get("wfStamps") if self._wf is not None else None
-        dirty = set()
-        dropped = 0
-        for k, item in enumerate(routing):
-            conn, xid, slot, _req, t_arrival = item[0], item[1], item[2], \
-                item[3], item[4]
-            result = results[k] if results else None
-            slot[0] = build_flow_reply(self.server, xid, result, shed_retry)
-            if wf_stamps is not None:
-                ctx = _req[3] if len(_req) > 3 else None
-                slot[1] = (t_arrival, item[5], t_submit, wf_stamps, t_done,
-                           ctx.trace_id if ctx is not None else None)
-            if conn.closed:
-                dropped += 1
-            else:
-                dirty.add(conn)
-            self._rtt_ms.append((t_done - t_arrival) * 1e3)
-        self._queue_wait_ms.append((t_done - t_submit) * 1e3)
-        if dropped:
-            with self._stats_lock:
-                self.dropped_replies += dropped
-        if dirty:
-            with self._dirty_lock:
-                self._dirty.update(dirty)
-            self._wake()
+        with span(prof.REACTOR_RESOLVE):
+            results = box.get("results")
+            shed_retry = box.get("shed_retry_after_ms")
+            t_done = time.perf_counter()
+            # Waterfall stamps: admitted groups carry the
+            # batcher's drain/dispatch/device marks; together with the
+            # reactor-side marks they chain gap-free into the 8-stage
+            # record _flush observes. Sheds/fails carry no stamps.
+            wf_stamps = box.get("wfStamps") if self._wf is not None else None
+            dirty = set()
+            dropped = 0
+            for k, item in enumerate(routing):
+                conn, xid, slot, _req, t_arrival = item[0], item[1], item[2], \
+                    item[3], item[4]
+                result = results[k] if results else None
+                slot[0] = build_flow_reply(self.server, xid, result, shed_retry)
+                if wf_stamps is not None:
+                    ctx = _req[3] if len(_req) > 3 else None
+                    slot[1] = (t_arrival, item[5], t_submit, wf_stamps, t_done,
+                               ctx.trace_id if ctx is not None else None)
+                if conn.closed:
+                    dropped += 1
+                else:
+                    dirty.add(conn)
+                self._rtt_ms.append((t_done - t_arrival) * 1e3)
+            self._queue_wait_ms.append((t_done - t_submit) * 1e3)
+            if dropped:
+                with self._stats_lock:
+                    self.dropped_replies += dropped
+            if dirty:
+                with self._dirty_lock:
+                    self._dirty.update(dirty)
+                self._wake()
 
     # -- non-FLOW compute (worker pool) ------------------------------------
 
@@ -532,36 +537,37 @@ class WireReactor:
         carrying a waterfall stamp record complete their 8-stage chain
         here (reply-slot wait ends at the pick, flush ends after the
         bytes are handed to the socket layer) and land in the recorder."""
-        wf = self._wf
-        t_pick = time.perf_counter() if wf is not None else 0.0
-        chunks = []
-        recs = None
-        while conn.replies and conn.replies[0][0] is not None:
-            slot = conn.replies.popleft()
-            chunks.append(slot[0])
-            if slot[1] is not None:
-                if recs is None:
-                    recs = []
-                recs.append(slot[1])
-        if chunks:
-            data = mutate_reply(b"".join(chunks))
-            if data:
-                conn.outq.append(data)
-                conn.out_bytes += len(data)
-        self._try_send(conn)
-        if recs and wf is not None:
-            t_sent = time.perf_counter()
-            for (t_arr, t_stg, t_sub, (t_drn, t_dsp, t_dev), t_fill,
-                 trace_id) in recs:
-                wf.observe_wire((
-                    (t_stg - t_arr) * 1e3,   # read: recv -> parse+stage
-                    (t_sub - t_stg) * 1e3,   # coalesce: stage -> submit
-                    (t_drn - t_sub) * 1e3,   # queue: submit -> drain
-                    (t_dsp - t_drn) * 1e3,   # dispatch: drain -> device
-                    (t_dev - t_dsp) * 1e3,   # device: dispatch -> harvest
-                    (t_fill - t_dev) * 1e3,  # harvest: wake -> slot fill
-                    (t_pick - t_fill) * 1e3,  # reply: fill -> flush pick
-                    (t_sent - t_pick) * 1e3), trace_id)  # flush
+        with span(prof.REACTOR_FLUSH):
+            wf = self._wf
+            t_pick = time.perf_counter() if wf is not None else 0.0
+            chunks = []
+            recs = None
+            while conn.replies and conn.replies[0][0] is not None:
+                slot = conn.replies.popleft()
+                chunks.append(slot[0])
+                if slot[1] is not None:
+                    if recs is None:
+                        recs = []
+                    recs.append(slot[1])
+            if chunks:
+                data = mutate_reply(b"".join(chunks))
+                if data:
+                    conn.outq.append(data)
+                    conn.out_bytes += len(data)
+            self._try_send(conn)
+            if recs and wf is not None:
+                t_sent = time.perf_counter()
+                for (t_arr, t_stg, t_sub, (t_drn, t_dsp, t_dev), t_fill,
+                     trace_id) in recs:
+                    wf.observe_wire((
+                        (t_stg - t_arr) * 1e3,   # read: recv -> parse+stage
+                        (t_sub - t_stg) * 1e3,   # coalesce: stage -> submit
+                        (t_drn - t_sub) * 1e3,   # queue: submit -> drain
+                        (t_dsp - t_drn) * 1e3,   # dispatch: drain -> device
+                        (t_dev - t_dsp) * 1e3,   # device: dispatch -> harvest
+                        (t_fill - t_dev) * 1e3,  # harvest: wake -> slot fill
+                        (t_pick - t_fill) * 1e3,  # reply: fill -> flush pick
+                        (t_sent - t_pick) * 1e3), trace_id)  # flush
 
     def _try_send(self, conn: _Conn) -> None:
         while conn.outq:

@@ -36,6 +36,8 @@ from sentinel_tpu.cluster.constants import (
 )
 from sentinel_tpu.cluster.token_service import DefaultTokenService
 from sentinel_tpu.core.config import config
+from sentinel_tpu.metrics import profiling as prof
+from sentinel_tpu.metrics.profiling import span
 from sentinel_tpu.resilience import DeadlineBudget, faults
 
 
@@ -226,21 +228,22 @@ class _Batcher:
         """Resolve one in-flight fused batch: the np readback happens
         here, outside the service lock — an async device death fails
         exactly this batch's groups (the drain loop keeps running)."""
-        try:
-            results = self.service.harvest_tokens(ticket)[:n_flat]
-        except Exception as ex:  # noqa: BLE001 — poison harvest
-            from sentinel_tpu.log.record_log import record_log
+        with span(prof.BATCHER_HARVEST):
+            try:
+                results = self.service.harvest_tokens(ticket)[:n_flat]
+            except Exception as ex:  # noqa: BLE001 — poison harvest
+                from sentinel_tpu.log.record_log import record_log
 
-            record_log.warn("token batch harvest failed: %r", ex)
-            self._fail(groups)
-            return
-        wf = self.waterfall
-        if wf is not None:
-            t_device = time.perf_counter()
-            wf.observe_batch((t_device - t_dispatch) * 1e3, n_flat)
-            self._complete(groups, results, (t_drain, t_dispatch, t_device))
-        else:
-            self._complete(groups, results)
+                record_log.warn("token batch harvest failed: %r", ex)
+                self._fail(groups)
+                return
+            wf = self.waterfall
+            if wf is not None:
+                t_device = time.perf_counter()
+                wf.observe_batch((t_device - t_dispatch) * 1e3, n_flat)
+                self._complete(groups, results, (t_drain, t_dispatch, t_device))
+            else:
+                self._complete(groups, results)
 
     def _run(self):
         from collections import deque
@@ -276,16 +279,17 @@ class _Batcher:
                 self._stop.set()
                 return
             # Linger briefly so concurrent clients fold into one step.
-            deadline = threading.Event()
-            deadline.wait(self.linger_s)
-            n = len(first[0])
-            while n < self.max_batch:
-                try:
-                    g = self._queue.get_nowait()
-                except queue.Empty:
-                    break
-                groups.append(g)
-                n += len(g[0])
+            with span(prof.BATCHER_LINGER):
+                deadline = threading.Event()
+                deadline.wait(self.linger_s)
+                n = len(first[0])
+                while n < self.max_batch:
+                    try:
+                        g = self._queue.get_nowait()
+                    except queue.Empty:
+                        break
+                    groups.append(g)
+                    n += len(g[0])
             # Deadline-aware shed BEFORE the device step: a group whose
             # budget expired while queued is dead weight — its client
             # already timed out — and spending a device step on it only
@@ -303,59 +307,60 @@ class _Batcher:
             groups = live
             if not groups:
                 continue
-            flat = [r for g in groups for r in g[0]]
-            # Bound jit specializations: request_tokens jits per batch
-            # LENGTH, and group granularity makes lengths client-
-            # controlled — unpadded, a client sending varying burst
-            # sizes would drive unbounded recompilation (and stall all
-            # token traffic per new width). pad_width keeps small
-            # batches (<= 64) at their EXACT width (their compiles are
-            # fast; padding the first 1-request acquire to 16 measurably
-            # outlasted the client's 2s request timeout — r5 review),
-            # larger bursts ride a coarse ladder; padding rows carry a
-            # None flow id -> slot -1 -> NO_RULE_EXISTS, get sliced off.
-            n_flat = len(flat)
-            width = pad_width(n_flat)
-            padded = flat + [(None, 0, False)] * (width - n_flat)
-            dispatch = getattr(self.service, "dispatch_tokens", None)
-            if dispatch is None or self.inflight_depth <= 1:
-                # Synchronous drain: services without the dispatch/
-                # harvest split (stubs), or depth pinned to 1.
-                t_dispatch = time.perf_counter()
+            with span(prof.BATCHER_DISPATCH):
+                flat = [r for g in groups for r in g[0]]
+                # Bound jit specializations: request_tokens jits per batch
+                # LENGTH, and group granularity makes lengths client-
+                # controlled — unpadded, a client sending varying burst
+                # sizes would drive unbounded recompilation (and stall all
+                # token traffic per new width). pad_width keeps small
+                # batches (<= 64) at their EXACT width (their compiles are
+                # fast; padding the first 1-request acquire to 16 measurably
+                # outlasted the client's 2s request timeout),
+                # larger bursts ride a coarse ladder; padding rows carry a
+                # None flow id -> slot -1 -> NO_RULE_EXISTS, get sliced off.
+                n_flat = len(flat)
+                width = pad_width(n_flat)
+                padded = flat + [(None, 0, False)] * (width - n_flat)
+                dispatch = getattr(self.service, "dispatch_tokens", None)
+                if dispatch is None or self.inflight_depth <= 1:
+                    # Synchronous drain: services without the dispatch/
+                    # harvest split (stubs), or depth pinned to 1.
+                    t_dispatch = time.perf_counter()
+                    try:
+                        results = self.service.request_tokens(padded)[:n_flat]
+                    except Exception as ex:  # a poison batch must not kill the loop
+                        from sentinel_tpu.log.record_log import record_log
+
+                        record_log.warn("token batch failed: %r", ex)
+                        self._fail(groups)
+                        continue
+                    wf = self.waterfall
+                    if wf is not None:
+                        t_device = time.perf_counter()
+                        wf.observe_batch((t_device - t_dispatch) * 1e3, n_flat)
+                        self._complete(groups, results,
+                                       (t_drain, t_dispatch, t_device))
+                    else:
+                        self._complete(groups, results)
+                    continue
+                # Pipelined drain: keep at most inflight_depth fused batches
+                # on the device stream. Each dispatch consumes the DONATED
+                # previous state, so execution order is forced by the data
+                # dependency — verdicts stay bit-identical to the sync drain
+                # (same argument as docs/SEMANTICS.md "Pipeline ordering").
+                while len(inflight) >= self.inflight_depth:
+                    self._harvest(*inflight.popleft())
                 try:
-                    results = self.service.request_tokens(padded)[:n_flat]
-                except Exception as ex:  # a poison batch must not kill the loop
+                    ticket = dispatch(padded)
+                except Exception as ex:  # a poison dispatch must not kill the loop
                     from sentinel_tpu.log.record_log import record_log
 
-                    record_log.warn("token batch failed: %r", ex)
+                    record_log.warn("token batch dispatch failed: %r", ex)
                     self._fail(groups)
                     continue
-                wf = self.waterfall
-                if wf is not None:
-                    t_device = time.perf_counter()
-                    wf.observe_batch((t_device - t_dispatch) * 1e3, n_flat)
-                    self._complete(groups, results,
-                                   (t_drain, t_dispatch, t_device))
-                else:
-                    self._complete(groups, results)
-                continue
-            # Pipelined drain: keep at most inflight_depth fused batches
-            # on the device stream. Each dispatch consumes the DONATED
-            # previous state, so execution order is forced by the data
-            # dependency — verdicts stay bit-identical to the sync drain
-            # (same argument as docs/SEMANTICS.md "Pipeline ordering").
-            while len(inflight) >= self.inflight_depth:
-                self._harvest(*inflight.popleft())
-            try:
-                ticket = dispatch(padded)
-            except Exception as ex:  # a poison dispatch must not kill the loop
-                from sentinel_tpu.log.record_log import record_log
-
-                record_log.warn("token batch dispatch failed: %r", ex)
-                self._fail(groups)
-                continue
-            inflight.append((ticket, groups, n_flat,
-                             t_drain, time.perf_counter()))
+                inflight.append((ticket, groups, n_flat,
+                                 t_drain, time.perf_counter()))
             if self._queue.empty():
                 # Idle queue ⇒ immediate harvest: the no-concurrency
                 # latency floor stays one step, overlap only engages

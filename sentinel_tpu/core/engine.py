@@ -46,7 +46,8 @@ class DeviceDispatchError(RuntimeError):
     stats ephemeral); catchers decide their own degradation — the sync
     entry path fails open, batch-API callers see the typed error."""
 from sentinel_tpu.core.registry import NodeRegistry, ORIGIN_ID_NONE
-from sentinel_tpu.metrics.profiling import StepTimer, timed_call
+from sentinel_tpu.metrics import profiling as prof
+from sentinel_tpu.metrics.profiling import StepTimer, span, timed_call
 from sentinel_tpu.resilience import DeadlineBudget
 
 
@@ -1320,9 +1321,10 @@ class SentinelEngine:
         gates only genuinely-recent refreshes."""
         if 0 <= now_ms - self._signals_refreshed_ms < 1000:
             return
-        self._signals_refreshed_ms = now_ms
-        self._state = self._state._replace(
-            sys_signals=jnp.asarray(self.system_status.snapshot()))
+        with span(prof.ADMIT_REFRESH_SIGNALS):
+            self._signals_refreshed_ms = now_ms
+            self._state = self._state._replace(
+                sys_signals=jnp.asarray(self.system_status.snapshot()))
 
     # -- public API --------------------------------------------------------
 
@@ -2058,14 +2060,20 @@ class SentinelEngine:
                 return 0, 0  # fail open, like fallbackToLocalOrPass
             return int(dec.reason[0]), int(dec.wait_us[0])
 
-    def _run_entry_batch_locked(self, batch: EntryBatch) -> Decisions:
+    def _run_entry_batch_locked(self, batch: EntryBatch,
+                                now_ms: Optional[int] = None,
+                                sample: bool = True) -> Decisions:
+        """One entry dispatch, engine lock held: the synchronous path
+        and the batch API alike. ``sample=False`` (the batch API, whose
+        caller reads the verdicts back itself) records the dispatch in
+        the StepTimer without a sampled sync."""
         self._ensure_compiled()
-        now = self.now_ms()
+        now = now_ms if now_ms is not None else self.now_ms()
         self._refresh_signals(now)
         try:
             self._state, dec = timed_call(
                 self.step_timer, "entry", batch.size, self._entry_jit,
-                self._state, self._rules, batch, now,
+                self._state, self._rules, batch, now, sample=sample,
                 occupy_timeout_ms=self._occupy_timeout_ms,
                 shadow_rules=self._shadow_rules,
                 canary_bps=self._canary_bps,
@@ -2075,22 +2083,25 @@ class SentinelEngine:
             raise DeviceDispatchError(f"entry dispatch failed: {ex!r:.200}") from ex
         # Sampled decision traces: enqueue only (the worker materializes
         # off this thread) — never blocks the step stream.
-        self.traces.submit(batch, dec, now)
-        self._observe_population(batch)
+        with span(prof.ADMIT_DECISION_TRACES):
+            self.traces.submit(batch, dec, now)
+        with span(prof.ADMIT_POPULATION):
+            self._observe_population(batch)
         return dec
 
     def _run_entry_batch(self, batch: EntryBatch) -> Decisions:
         with self._lock:
             return self._run_entry_batch_locked(batch)
 
-    def _run_exit_batch(self, batch: ExitBatch) -> None:
+    def _run_exit_batch(self, batch: ExitBatch, now_ms: Optional[int] = None,
+                        sample: bool = True) -> None:
         with self._lock:
             self._ensure_compiled()
-            now = self.now_ms()
+            now = now_ms if now_ms is not None else self.now_ms()
             try:
                 self._state = timed_call(
                     self.step_timer, "exit", batch.size, self._exit_jit,
-                    self._state, self._rules, batch, now,
+                    self._state, self._rules, batch, now, sample=sample,
                     shadow_rules=self._shadow_rules)
             except Exception as ex:  # noqa: BLE001
                 self._state = None
@@ -2261,37 +2272,12 @@ class SentinelEngine:
     # -- batch API (bench / pipelined engine / cluster frontends) ---------
 
     def check_batch(self, batch: EntryBatch, now_ms: Optional[int] = None) -> Decisions:
-        with self._lock:
-            self._ensure_compiled()
-            now = now_ms if now_ms is not None else self.now_ms()
-            self._refresh_signals(now)
-            try:
-                self._state, dec = self._entry_jit(
-                    self._state, self._rules, batch, now,
-                    occupy_timeout_ms=self._occupy_timeout_ms,
-                    shadow_rules=self._shadow_rules,
-                    canary_bps=self._canary_bps,
-                    canary_salt=self._canary_salt)
-            except Exception as ex:  # noqa: BLE001
-                self._state = None
-                raise DeviceDispatchError(
-                    f"entry dispatch failed: {ex!r:.200}") from ex
-            self.traces.submit(batch, dec, now)
-            self._observe_population(batch)
-            return dec
+        with span(prof.ADMIT_CHECK_BATCH), self._lock:
+            return self._run_entry_batch_locked(batch, now_ms, sample=False)
 
     def complete_batch(self, batch: ExitBatch, now_ms: Optional[int] = None) -> None:
-        with self._lock:
-            self._ensure_compiled()
-            now = now_ms if now_ms is not None else self.now_ms()
-            try:
-                self._state = self._exit_jit(self._state, self._rules, batch,
-                                             now,
-                                             shadow_rules=self._shadow_rules)
-            except Exception as ex:  # noqa: BLE001
-                self._state = None
-                raise DeviceDispatchError(
-                    f"exit dispatch failed: {ex!r:.200}") from ex
+        with span(prof.ADMIT_COMPLETE_BATCH):
+            self._run_exit_batch(batch, now_ms, sample=False)
 
     # -- metric log source (ops plane) ------------------------------------
 

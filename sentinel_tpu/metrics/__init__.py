@@ -5,10 +5,9 @@ rotating log + index, and range reads for the ops plane.
 
 from sentinel_tpu.metrics.metric_node import MetricNode
 from sentinel_tpu.metrics.profiling import StepTimer
-from sentinel_tpu.metrics.profiling import trace as profile_trace
 from sentinel_tpu.metrics.searcher import MetricSearcher
 from sentinel_tpu.metrics.timer import MetricTimerListener
 from sentinel_tpu.metrics.writer import MetricWriter
 
 __all__ = ["MetricNode", "MetricSearcher", "MetricTimerListener",
-           "MetricWriter", "StepTimer", "profile_trace"]
+           "MetricWriter", "StepTimer"]

@@ -1,4 +1,4 @@
-"""Step timing + kernel tracing (SURVEY.md §5 "Tracing / profiling").
+"""Step timing + program spans (SURVEY.md §5 "Tracing / profiling").
 
 The reference has no in-process profiler (introspection stops at the
 command API's live-stat dumps); the survey's TPU plan adds two things the
@@ -13,20 +13,56 @@ tensor design makes natural:
     (``csp.sentinel.profile.syncEvery``). Snapshots report p50/p95/p99
     per kind and feed the ``profile`` ops command plus the OpenMetrics
     exporter (sentinel_tpu/telemetry/).
-  * **kernel traces** — :func:`trace` wraps ``jax.profiler`` so a window
-    of real traffic can be captured for TensorBoard/Perfetto kernel-level
-    inspection.
+  * **program spans** — :func:`span` marks host work at the seams of
+    admission, the wire and the token batcher with a
+    ``jax.profiler.TraceAnnotation``, so a profiler trace (``jax.profiler
+    .start_trace``) shows it on the same timeline as the device's XLA
+    modules and ops. With no trace running a span costs well under a
+    microsecond. Spans sit at batch, call or loop-iteration grain, never
+    per entry, and surround work, not idle waits (the batcher's linger,
+    a deliberate wait that holds the device idle, is the one exception).
+    The fused step's own sections carry ``jax.named_scope`` names
+    (ops/step.py), which land in the compiled ops' metadata.
 """
 
 from __future__ import annotations
 
-import contextlib
 import math
 import threading
 import time
 from typing import Dict, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+# Program span names: one place, all prefixed ``sentinel.`` so a trace
+# reader tells them from any caller's own annotations.
+# Host admission (core/engine.py), batch API and synchronous path alike.
+ADMIT_CHECK_BATCH = "sentinel.admit.check_batch"        # all of check_batch
+ADMIT_ENTRY_DISPATCH = "sentinel.admit.entry_dispatch"  # the entry jit call
+ADMIT_REFRESH_SIGNALS = "sentinel.admit.refresh_signals"  # when it copies
+ADMIT_DECISION_TRACES = "sentinel.admit.decision_traces"  # traces.submit
+ADMIT_POPULATION = "sentinel.admit.population"          # _observe_population
+ADMIT_COMPLETE_BATCH = "sentinel.admit.complete_batch"  # all of complete_batch
+ADMIT_EXIT_DISPATCH = "sentinel.admit.exit_dispatch"    # the exit jit call
+# Wire frontend (cluster/reactor.py).
+REACTOR_READ = "sentinel.reactor.read"
+REACTOR_SUBMIT = "sentinel.reactor.submit"
+REACTOR_FLUSH = "sentinel.reactor.flush"
+REACTOR_RESOLVE = "sentinel.reactor.resolve"
+# Token batcher (cluster/server.py _Batcher).
+BATCHER_LINGER = "sentinel.batcher.linger"
+BATCHER_DISPATCH = "sentinel.batcher.dispatch"
+BATCHER_HARVEST = "sentinel.batcher.harvest"
+# Token client (cluster/client.py).
+CLIENT_SEND = "sentinel.client.send"
+CLIENT_RECEIVE = "sentinel.client.receive"
+
+
+def span(name: str) -> TraceAnnotation:
+    """A program span: ``with span(ADMIT_CHECK_BATCH): ...`` records the
+    block on the profiler's timeline while a trace runs."""
+    return TraceAnnotation(name)
 
 
 def _pct(values, q: float) -> float:
@@ -196,31 +232,20 @@ class StepTimer:
             self._sync.clear()
 
 
-@contextlib.contextmanager
-def trace(logdir: str):
-    """Capture a kernel-level device trace of everything inside the block.
-
-    ``with profiling.trace("/tmp/sentinel-trace"): ...`` then open the
-    directory in TensorBoard (or xprof) to see per-kernel timing of the
-    fused step. Thin wrapper so callers don't import jax.profiler
-    directly; swallows nothing — an unsupported backend raises.
-    """
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield logdir
-    finally:
-        jax.profiler.stop_trace()
+_DISPATCH_SPANS = {"entry": ADMIT_ENTRY_DISPATCH, "exit": ADMIT_EXIT_DISPATCH}
 
 
-def timed_call(timer: StepTimer, kind: str, batch_n: int, fn, *args, **kwargs):
-    """Run ``fn(*args, **kwargs)`` (a jitted dispatch returning a pytree),
-    recording enqueue wall always and blocking for a true step wall on
-    sampled dispatches."""
-    do_sync = timer.should_sync(kind)
+def timed_call(timer: StepTimer, kind: str, batch_n: int, fn, *args,
+               sample: bool = True, **kwargs):
+    """Run ``fn(*args, **kwargs)`` (a jitted ``"entry"`` or ``"exit"``
+    dispatch returning a pytree) inside its program span, recording the
+    enqueue wall always and, with ``sample``, blocking for a true step
+    wall on sampled dispatches. A caller that reads the result back
+    itself (the batch API) passes ``sample=False``."""
+    do_sync = sample and timer.should_sync(kind)
     t0 = time.perf_counter()
-    out = fn(*args, **kwargs)
+    with span(_DISPATCH_SPANS[kind]):
+        out = fn(*args, **kwargs)
     enqueue_ms = (time.perf_counter() - t0) * 1e3
     sync_ms = None
     if do_sync:

@@ -516,6 +516,7 @@ def _shadow_entry_eval(
             (s_fv.state, s_pv.state, s_dv.state), sh_w1, fam_blocks, s_slot)
 
 
+@jax.named_scope("entry_step")
 def entry_step(
     state: SentinelState,
     rules: RulePack,
@@ -556,26 +557,28 @@ def entry_step(
     cross-device shadow contributions, mirroring ``extra_pass`` /
     ``extra_cms``."""
     now_ms = jnp.asarray(now_ms, jnp.int64)
-    w1 = W.rotate(state.w1, now_ms, spec1)
-    # Minute-window commits are staged in the [E, R] second accumulator and
-    # folded at most once per second; readers (BBR check below, host metric
-    # sealing) combine w60 + the live accumulator themselves.
-    w60, sec, tele, flight = _roll_second(state.w60, state.sec,
-                                          state.telemetry, state.flight,
-                                          now_ms)
+    with jax.named_scope("roll"):
+        w1 = W.rotate(state.w1, now_ms, spec1)
+        # Minute-window commits are staged in the [E, R] second accumulator
+        # and folded at most once per second; readers (BBR check below, host
+        # metric sealing) combine w60 + the live accumulator themselves.
+        w60, sec, tele, flight = _roll_second(state.w60, state.sec,
+                                              state.telemetry, state.flight,
+                                              now_ms)
 
-    # Land pending occupy borrows: once the bucket after the granting one is
-    # current, its borrowed counts become real PASS there (reference:
-    # OccupiableBucketLeapArray.resetWindowTo transfers the borrow bucket).
-    # A jump of 2+ buckets means the target bucket already expired — the
-    # borrows are dropped, like a borrow bucket the ring rotated past.
-    idx1 = W.current_index(now_ms, spec1)
-    cur_start = now_ms - now_ms % spec1.bucket_ms
-    moved = (state.occupied_stamp >= 0) & (cur_start != state.occupied_stamp)
-    land = moved & (cur_start == state.occupied_stamp + spec1.bucket_ms)
-    w1 = w1._replace(counts=w1.counts.at[idx1, C.MetricEvent.PASS].add(
-        jnp.where(land, state.occupied_next, 0)))
-    occupied_next = jnp.where(moved, 0, state.occupied_next)
+        # Land pending occupy borrows: once the bucket after the granting one
+        # is current, its borrowed counts become real PASS there (reference:
+        # OccupiableBucketLeapArray.resetWindowTo transfers the borrow
+        # bucket). A jump of 2+ buckets means the target bucket already
+        # expired — the borrows are dropped, like a borrow bucket the ring
+        # rotated past.
+        idx1 = W.current_index(now_ms, spec1)
+        cur_start = now_ms - now_ms % spec1.bucket_ms
+        moved = (state.occupied_stamp >= 0) & (cur_start != state.occupied_stamp)
+        land = moved & (cur_start == state.occupied_stamp + spec1.bucket_ms)
+        w1 = w1._replace(counts=w1.counts.at[idx1, C.MetricEvent.PASS].add(
+            jnp.where(land, state.occupied_next, 0)))
+        occupied_next = jnp.where(moved, 0, state.occupied_next)
 
     valid = batch.cluster_row >= 0
     reason = jnp.where(valid, C.BlockReason.PASS, -1).astype(jnp.int32)
@@ -600,60 +603,66 @@ def entry_step(
 
     # --- rule slots (order mirrors the reference chain: authority →
     # system → param-flow → flow → degrade) --------------------------------
-    av = A.check_authority(rules.authority, batch, valid & (~decided))
-    auth_blocked = av.blocked
-    reason = jnp.where(valid & (~decided) & auth_blocked, C.BlockReason.AUTHORITY, reason)
-    rule_slot = jnp.where(valid & (~decided) & auth_blocked, av.slot, rule_slot)
-    blocked = blocked | auth_blocked
-    decided = decided | blocked
-
-    cand = valid & (~decided)
-    sys_blocked = Y.check_system(rules.system, state.sys_signals, w1, w60,
-                                 sec.counts, state.cur_threads, batch, cand,
-                                 now_ms, spec1=spec1)
-    reason = jnp.where(cand & sys_blocked, C.BlockReason.SYSTEM, reason)
-    # System rules are one global set, not per-resource slots: slot 0.
-    rule_slot = jnp.where(cand & sys_blocked, 0, rule_slot)
-    blocked = blocked | sys_blocked
-    decided = decided | blocked
-
-    cand = valid & (~decided)
-    pv = P.check_param_flow(rules.param, state.param, batch, now_ms, cand,
-                            extra_cms=extra_cms)
-    reason = jnp.where(cand & pv.blocked, C.BlockReason.PARAM_FLOW, reason)
-    rule_slot = jnp.where(cand & pv.blocked, pv.slot, rule_slot)
-    blocked = blocked | pv.blocked
-    decided = decided | blocked
-
-    for chk_idx, chk in enumerate(extra_checkers):
-        cand = valid & (~decided)
-        custom_blocked = cand & chk(state._replace(w1=w1), rules, batch,
-                                    now_ms, cand)
-        reason = jnp.where(custom_blocked, C.BlockReason.CUSTOM, reason)
-        # CUSTOM attribution: the splice position of the blocking checker.
-        rule_slot = jnp.where(custom_blocked, chk_idx, rule_slot)
-        blocked = blocked | custom_blocked
+    with jax.named_scope("authority"):
+        av = A.check_authority(rules.authority, batch, valid & (~decided))
+        auth_blocked = av.blocked
+        reason = jnp.where(valid & (~decided) & auth_blocked, C.BlockReason.AUTHORITY, reason)
+        rule_slot = jnp.where(valid & (~decided) & auth_blocked, av.slot, rule_slot)
+        blocked = blocked | auth_blocked
         decided = decided | blocked
 
-    fv = F.check_flow(rules.flow, state.flow, w1, state.cur_threads, batch, now_ms, decided,
-                      extra_pass=extra_pass, occupied_next=occupied_next,
-                      extra_next=extra_next,
-                      extra_pass_global=extra_pass_global,
-                      extra_next_global=extra_next_global, spec=spec1,
-                      occupy_timeout_ms=occupy_timeout_ms)
-    reason = jnp.where(valid & (~decided) & fv.blocked, C.BlockReason.FLOW, reason)
-    rule_slot = jnp.where(valid & (~decided) & fv.blocked, fv.slot, rule_slot)
-    blocked = blocked | fv.blocked
-    decided = decided | blocked
+    with jax.named_scope("system"):
+        cand = valid & (~decided)
+        sys_blocked = Y.check_system(rules.system, state.sys_signals, w1, w60,
+                                     sec.counts, state.cur_threads, batch, cand,
+                                     now_ms, spec1=spec1)
+        reason = jnp.where(cand & sys_blocked, C.BlockReason.SYSTEM, reason)
+        # System rules are one global set, not per-resource slots: slot 0.
+        rule_slot = jnp.where(cand & sys_blocked, 0, rule_slot)
+        blocked = blocked | sys_blocked
+        decided = decided | blocked
+
+    with jax.named_scope("param"):
+        cand = valid & (~decided)
+        pv = P.check_param_flow(rules.param, state.param, batch, now_ms, cand,
+                                extra_cms=extra_cms)
+        reason = jnp.where(cand & pv.blocked, C.BlockReason.PARAM_FLOW, reason)
+        rule_slot = jnp.where(cand & pv.blocked, pv.slot, rule_slot)
+        blocked = blocked | pv.blocked
+        decided = decided | blocked
+
+    with jax.named_scope("custom"):
+        for chk_idx, chk in enumerate(extra_checkers):
+            cand = valid & (~decided)
+            custom_blocked = cand & chk(state._replace(w1=w1), rules, batch,
+                                        now_ms, cand)
+            reason = jnp.where(custom_blocked, C.BlockReason.CUSTOM, reason)
+            # CUSTOM attribution: the splice position of the blocking checker.
+            rule_slot = jnp.where(custom_blocked, chk_idx, rule_slot)
+            blocked = blocked | custom_blocked
+            decided = decided | blocked
+
+    with jax.named_scope("flow"):
+        fv = F.check_flow(rules.flow, state.flow, w1, state.cur_threads, batch, now_ms, decided,
+                          extra_pass=extra_pass, occupied_next=occupied_next,
+                          extra_next=extra_next,
+                          extra_pass_global=extra_pass_global,
+                          extra_next_global=extra_next_global, spec=spec1,
+                          occupy_timeout_ms=occupy_timeout_ms)
+        reason = jnp.where(valid & (~decided) & fv.blocked, C.BlockReason.FLOW, reason)
+        rule_slot = jnp.where(valid & (~decided) & fv.blocked, fv.slot, rule_slot)
+        blocked = blocked | fv.blocked
+        decided = decided | blocked
 
     # Occupy grants leave the chain before the degrade slot (reference:
     # PriorityWaitException propagates out of FlowSlot).
-    granted = valid & (~decided) & fv.occupied
-    dv = D.check_degrade(rules.degrade, state.degrade, batch, now_ms,
-                         valid & (~decided) & (~granted))
-    reason = jnp.where(valid & (~decided) & dv.blocked, C.BlockReason.DEGRADE, reason)
-    rule_slot = jnp.where(valid & (~decided) & dv.blocked, dv.slot, rule_slot)
-    blocked = blocked | dv.blocked
+    with jax.named_scope("degrade"):
+        granted = valid & (~decided) & fv.occupied
+        dv = D.check_degrade(rules.degrade, state.degrade, batch, now_ms,
+                             valid & (~decided) & (~granted))
+        reason = jnp.where(valid & (~decided) & dv.blocked, C.BlockReason.DEGRADE, reason)
+        rule_slot = jnp.where(valid & (~decided) & dv.blocked, dv.slot, rule_slot)
+        blocked = blocked | dv.blocked
 
     # --- shadow lanes (sentinel_tpu/rollout/) -----------------------------
     # Candidate-world verdicts ride the same step; canary lanes swap their
@@ -662,71 +671,73 @@ def entry_step(
     shadow_new = state.shadow
     s_eval = None
     wait_pick = jnp.maximum(fv.wait_us, pv.wait_us)
-    if shadow_rules is not None and state.shadow is not None:
-        from sentinel_tpu.rollout.canary import device_in_canary
+    with jax.named_scope("shadow"):
+        if shadow_rules is not None and state.shadow is not None:
+            from sentinel_tpu.rollout.canary import device_in_canary
 
-        s_eval = _shadow_entry_eval(
-            state, shadow_rules, batch, now_ms, w1, w60, sec.counts, spec1,
-            occupy_timeout_ms, shadow_extra_pass=shadow_extra_pass,
-            shadow_extra_cms=shadow_extra_cms)
-        (s_blocked, s_reason, s_wait_us, s_states, sh_w1, s_fam,
-         s_slot) = s_eval
-        if canary_bps is not None:
-            # Canary enforcement: deterministic (origin, context) hash
-            # selects a stable slice of traffic the candidate governs.
-            # Pre-decided lanes (remote token verdicts, lease commits)
-            # and occupy-granted lanes stay live-governed — their
-            # decision was already made elsewhere.
-            mix = (valid & (~batch.pre_blocked) & (~batch.pre_passed)
-                   & (~granted)
-                   & device_in_canary(
-                       batch.origin_id, batch.context_id,
-                       0 if canary_salt is None else canary_salt,
-                       canary_bps))
-            blocked = jnp.where(mix, s_blocked, blocked)
-            reason = jnp.where(mix, s_reason, reason)
-            rule_slot = jnp.where(mix, s_slot, rule_slot)
-            wait_pick = jnp.where(mix, s_wait_us, wait_pick)
+            s_eval = _shadow_entry_eval(
+                state, shadow_rules, batch, now_ms, w1, w60, sec.counts, spec1,
+                occupy_timeout_ms, shadow_extra_pass=shadow_extra_pass,
+                shadow_extra_cms=shadow_extra_cms)
+            (s_blocked, s_reason, s_wait_us, s_states, sh_w1, s_fam,
+             s_slot) = s_eval
+            if canary_bps is not None:
+                # Canary enforcement: deterministic (origin, context) hash
+                # selects a stable slice of traffic the candidate governs.
+                # Pre-decided lanes (remote token verdicts, lease commits)
+                # and occupy-granted lanes stay live-governed — their
+                # decision was already made elsewhere.
+                mix = (valid & (~batch.pre_blocked) & (~batch.pre_passed)
+                       & (~granted)
+                       & device_in_canary(
+                           batch.origin_id, batch.context_id,
+                           0 if canary_salt is None else canary_salt,
+                           canary_bps))
+                blocked = jnp.where(mix, s_blocked, blocked)
+                reason = jnp.where(mix, s_reason, reason)
+                rule_slot = jnp.where(mix, s_slot, rule_slot)
+                wait_pick = jnp.where(mix, s_wait_us, wait_pick)
 
     # --- StatisticSlot commit --------------------------------------------
-    rows4 = _target_rows(batch.cluster_row, batch.dn_row, batch.origin_row, batch.entry_in)
-    admit = valid & (~blocked)
-    # Granted occupies don't commit PASS now: their pass lands in the bucket
-    # they borrowed (the fold above, next step). The minute staging gets
-    # PASS + OCCUPIED_PASS immediately on the rule-selected row (reference:
-    # StatisticNode.addOccupiedPass hits the minute counter at grant time).
-    pass_counts = jnp.where(admit & (~granted), batch.count, 0)
-    block_counts = jnp.where(valid & blocked, batch.count, 0)
-    pass4 = jnp.broadcast_to(pass_counts[:, None], rows4.shape)
-    block4 = jnp.broadcast_to(block_counts[:, None], rows4.shape)
+    with jax.named_scope("commit"):
+        rows4 = _target_rows(batch.cluster_row, batch.dn_row, batch.origin_row, batch.entry_in)
+        admit = valid & (~blocked)
+        # Granted occupies don't commit PASS now: their pass lands in the bucket
+        # they borrowed (the fold above, next step). The minute staging gets
+        # PASS + OCCUPIED_PASS immediately on the rule-selected row (reference:
+        # StatisticNode.addOccupiedPass hits the minute counter at grant time).
+        pass_counts = jnp.where(admit & (~granted), batch.count, 0)
+        block_counts = jnp.where(valid & blocked, batch.count, 0)
+        pass4 = jnp.broadcast_to(pass_counts[:, None], rows4.shape)
+        block4 = jnp.broadcast_to(block_counts[:, None], rows4.shape)
 
-    thread_inc = jnp.broadcast_to(jnp.where(admit, 1, 0)[:, None], rows4.shape)
-    extra_cols = [thread_inc]
-    sh_base = len(extra_cols)
-    if s_eval is not None:
-        # Every shadow commit — the shadow window's PASS plus all the
-        # would-verdict counter channels — rides the live commit's
-        # bincount as extra value columns: the one-hot operands (the
-        # expensive part on TPU) are shared, no second sweep. The LIVE
-        # counter channels need no columns at all — they are exactly
-        # delta[PASS] / delta[BLOCK].
-        s_pass_counts = jnp.where(valid & (~s_blocked), batch.count, 0)
-        s_block_counts = jnp.where(valid & s_blocked, batch.count, 0)
-        for col in (s_pass_counts, s_block_counts,
-                    *(jnp.where(m, batch.count, 0) for m in s_fam)):
-            extra_cols.append(jnp.broadcast_to(col[:, None], rows4.shape))
-    delta, extras = _event_delta(
-        rows4, [(C.MetricEvent.PASS, pass4, False),
-                (C.MetricEvent.BLOCK, block4, False)], w1.num_rows,
-        extra_cols=extra_cols)
-    w1, sec = _apply_delta(w1, sec, delta, now_ms, spec1)
-    occupied_next = occupied_next + fv.occ_add
-    occupied_stamp = cur_start
-    sec = sec._replace(counts=sec.counts
-                       .at[C.MetricEvent.PASS].add(fv.occ_add)
-                       .at[C.MetricEvent.OCCUPIED_PASS].add(fv.occ_add))
+        thread_inc = jnp.broadcast_to(jnp.where(admit, 1, 0)[:, None], rows4.shape)
+        extra_cols = [thread_inc]
+        sh_base = len(extra_cols)
+        if s_eval is not None:
+            # Every shadow commit — the shadow window's PASS plus all the
+            # would-verdict counter channels — rides the live commit's
+            # bincount as extra value columns: the one-hot operands (the
+            # expensive part on TPU) are shared, no second sweep. The LIVE
+            # counter channels need no columns at all — they are exactly
+            # delta[PASS] / delta[BLOCK].
+            s_pass_counts = jnp.where(valid & (~s_blocked), batch.count, 0)
+            s_block_counts = jnp.where(valid & s_blocked, batch.count, 0)
+            for col in (s_pass_counts, s_block_counts,
+                        *(jnp.where(m, batch.count, 0) for m in s_fam)):
+                extra_cols.append(jnp.broadcast_to(col[:, None], rows4.shape))
+        delta, extras = _event_delta(
+            rows4, [(C.MetricEvent.PASS, pass4, False),
+                    (C.MetricEvent.BLOCK, block4, False)], w1.num_rows,
+            extra_cols=extra_cols)
+        w1, sec = _apply_delta(w1, sec, delta, now_ms, spec1)
+        occupied_next = occupied_next + fv.occ_add
+        occupied_stamp = cur_start
+        sec = sec._replace(counts=sec.counts
+                           .at[C.MetricEvent.PASS].add(fv.occ_add)
+                           .at[C.MetricEvent.OCCUPIED_PASS].add(fv.occ_add))
 
-    cur_threads = state.cur_threads + extras[0].astype(jnp.int32)
+        cur_threads = state.cur_threads + extras[0].astype(jnp.int32)
 
     # Telemetry commit: ONE single-column scatter-add of the blocked
     # lanes into the staged per-(reason, ClusterNode) counters. In-place
@@ -740,40 +751,42 @@ def entry_step(
     # second-roll, and the second staging already carries every commit
     # including occupy grants (the ``occ_add`` adds below —
     # StatisticNode.addOccupiedPass semantics).
-    attr_ch = jnp.asarray(REASON_CHANNEL_TABLE)[
-        jnp.clip(reason, 0, REASON_CHANNEL_TABLE.shape[0] - 1)]
-    attr_on = valid & blocked & (attr_ch >= 0)
-    attr_rows = W.oob(jnp.where(attr_on, batch.cluster_row, -1), w1.num_rows)
-    # The (reason, rule-slot) staging shares the same mask: one more tiny
-    # scatter into a [A, SLOT_BINS] tensor (remote/pre-decided verdicts
-    # carry slot -1 and land in the "unknown" bin).
-    slot_bins = jnp.where(attr_on, slot_bin_index(rule_slot), NUM_SLOT_BINS)
-    tele = tele._replace(
-        stage_attr=tele.stage_attr.at[
-            jnp.maximum(attr_ch, 0), attr_rows].add(
-            jnp.where(attr_on, batch.count, 0), mode="drop"),
-        stage_slot=tele.stage_slot.at[
-            jnp.maximum(attr_ch, 0), slot_bins].add(
-            jnp.where(attr_on, batch.count, 0), mode="drop"))
+    with jax.named_scope("telemetry"):
+        attr_ch = jnp.asarray(REASON_CHANNEL_TABLE)[
+            jnp.clip(reason, 0, REASON_CHANNEL_TABLE.shape[0] - 1)]
+        attr_on = valid & blocked & (attr_ch >= 0)
+        attr_rows = W.oob(jnp.where(attr_on, batch.cluster_row, -1), w1.num_rows)
+        # The (reason, rule-slot) staging shares the same mask: one more tiny
+        # scatter into a [A, SLOT_BINS] tensor (remote/pre-decided verdicts
+        # carry slot -1 and land in the "unknown" bin).
+        slot_bins = jnp.where(attr_on, slot_bin_index(rule_slot), NUM_SLOT_BINS)
+        tele = tele._replace(
+            stage_attr=tele.stage_attr.at[
+                jnp.maximum(attr_ch, 0), attr_rows].add(
+                jnp.where(attr_on, batch.count, 0), mode="drop"),
+            stage_slot=tele.stage_slot.at[
+                jnp.maximum(attr_ch, 0), slot_bins].add(
+                jnp.where(attr_on, batch.count, 0), mode="drop"))
 
-    if s_eval is not None:
-        sh_w1 = sh_w1._replace(counts=sh_w1.counts.at[
-            idx1, C.MetricEvent.PASS].add(extras[sh_base].astype(jnp.int32)))
-        counts = state.shadow.counts
-        for ch, vec in (
-                (SH_WOULD_PASS, extras[sh_base]),
-                (SH_WOULD_BLOCK, extras[sh_base + 1]),
-                (SH_WB_AUTHORITY, extras[sh_base + 2]),
-                (SH_WB_SYSTEM, extras[sh_base + 3]),
-                (SH_WB_PARAM, extras[sh_base + 4]),
-                (SH_WB_FLOW, extras[sh_base + 5]),
-                (SH_WB_DEGRADE, extras[sh_base + 6]),
-                (SH_LIVE_PASS, delta[C.MetricEvent.PASS]),
-                (SH_LIVE_BLOCK, delta[C.MetricEvent.BLOCK])):
-            counts = counts.at[ch].add(vec.astype(jnp.int64))
-        shadow_new = ShadowState(
-            w1=sh_w1, flow=s_states[0], param=s_states[1],
-            degrade=s_states[2], counts=counts)
+    with jax.named_scope("shadow"):
+        if s_eval is not None:
+            sh_w1 = sh_w1._replace(counts=sh_w1.counts.at[
+                idx1, C.MetricEvent.PASS].add(extras[sh_base].astype(jnp.int32)))
+            counts = state.shadow.counts
+            for ch, vec in (
+                    (SH_WOULD_PASS, extras[sh_base]),
+                    (SH_WOULD_BLOCK, extras[sh_base + 1]),
+                    (SH_WB_AUTHORITY, extras[sh_base + 2]),
+                    (SH_WB_SYSTEM, extras[sh_base + 3]),
+                    (SH_WB_PARAM, extras[sh_base + 4]),
+                    (SH_WB_FLOW, extras[sh_base + 5]),
+                    (SH_WB_DEGRADE, extras[sh_base + 6]),
+                    (SH_LIVE_PASS, delta[C.MetricEvent.PASS]),
+                    (SH_LIVE_BLOCK, delta[C.MetricEvent.BLOCK])):
+                counts = counts.at[ch].add(vec.astype(jnp.int64))
+            shadow_new = ShadowState(
+                w1=sh_w1, flow=s_states[0], param=s_states[1],
+                degrade=s_states[2], counts=counts)
 
     wait_us = jnp.where(admit, wait_pick, 0)
 
@@ -789,6 +802,7 @@ def entry_step(
                                 rule_slot=rule_slot)
 
 
+@jax.named_scope("exit_step")
 def exit_step(
     state: SentinelState,
     rules: RulePack,
@@ -808,28 +822,30 @@ def exit_step(
     (SEMANTICS.md "Shadow-lane exactness").
     """
     now_ms = jnp.asarray(now_ms, jnp.int64)
-    w1 = W.rotate(state.w1, now_ms, spec1)
-    w60, sec, tele, flight = _roll_second(state.w60, state.sec,
-                                          state.telemetry, state.flight,
-                                          now_ms)
+    with jax.named_scope("roll"):
+        w1 = W.rotate(state.w1, now_ms, spec1)
+        w60, sec, tele, flight = _roll_second(state.w60, state.sec,
+                                              state.telemetry, state.flight,
+                                              now_ms)
 
     valid = batch.cluster_row >= 0
-    rows4 = _target_rows(batch.cluster_row, batch.dn_row, batch.origin_row, batch.entry_in)
+    with jax.named_scope("commit"):
+        rows4 = _target_rows(batch.cluster_row, batch.dn_row, batch.origin_row, batch.entry_in)
 
-    succ = jnp.where(valid & batch.success, batch.count, 0)
-    exc = jnp.where(valid & batch.error, batch.count, 0)
-    rt = jnp.where(valid & batch.success, batch.rt_ms, 0)
-    succ4 = jnp.broadcast_to(succ[:, None], rows4.shape)
-    exc4 = jnp.broadcast_to(exc[:, None], rows4.shape)
-    rt4 = jnp.broadcast_to(rt[:, None], rows4.shape)
+        succ = jnp.where(valid & batch.success, batch.count, 0)
+        exc = jnp.where(valid & batch.error, batch.count, 0)
+        rt = jnp.where(valid & batch.success, batch.rt_ms, 0)
+        succ4 = jnp.broadcast_to(succ[:, None], rows4.shape)
+        exc4 = jnp.broadcast_to(exc[:, None], rows4.shape)
+        rt4 = jnp.broadcast_to(rt[:, None], rows4.shape)
 
-    thread_dec = jnp.broadcast_to(jnp.where(valid, -1, 0)[:, None], rows4.shape)
-    delta, extras = _event_delta(
-        rows4, [(C.MetricEvent.SUCCESS, succ4, False),
-                (C.MetricEvent.EXCEPTION, exc4, False),
-                (C.MetricEvent.RT, rt4, True)], w1.num_rows,
-        extra_cols=[thread_dec])
-    w1, sec = _apply_delta(w1, sec, delta, now_ms, spec1)
+        thread_dec = jnp.broadcast_to(jnp.where(valid, -1, 0)[:, None], rows4.shape)
+        delta, extras = _event_delta(
+            rows4, [(C.MetricEvent.SUCCESS, succ4, False),
+                    (C.MetricEvent.EXCEPTION, exc4, False),
+                    (C.MetricEvent.RT, rt4, True)], w1.num_rows,
+            extra_cols=[thread_dec])
+        w1, sec = _apply_delta(w1, sec, delta, now_ms, spec1)
     # Device-side log-bucketed RT histogram (telemetry/attribution.py):
     # one single-column scatter-add of success completions into the
     # staged per-(bucket, ClusterNode) counters — per-resource latency
@@ -838,45 +854,51 @@ def exit_step(
     # entry commit's attribution note for why this beats extra bincount
     # columns); the int64 histogram folds at second-roll, and totals
     # ride sec.counts — no per-step write to the wide tensors.
-    bidx = rt_bucket_index(batch.rt_ms)
-    succ_mask = valid & batch.success
-    hist_rows = W.oob(jnp.where(succ_mask, batch.cluster_row, -1),
-                      w1.num_rows)
-    # Weight 1 per COMPLETION, not per acquire token: the RT sum records
-    # each completion's rt once (reference Tracer semantics), and the
-    # OpenMetrics histogram contract requires _bucket/_count/_sum to
-    # describe the same observation stream.
-    telemetry = tele._replace(stage_hist=tele.stage_hist.at[
-        bidx, hist_rows].add(jnp.where(succ_mask, 1, 0), mode="drop"))
+    with jax.named_scope("telemetry"):
+        bidx = rt_bucket_index(batch.rt_ms)
+        succ_mask = valid & batch.success
+        hist_rows = W.oob(jnp.where(succ_mask, batch.cluster_row, -1),
+                          w1.num_rows)
+        # Weight 1 per COMPLETION, not per acquire token: the RT sum records
+        # each completion's rt once (reference Tracer semantics), and the
+        # OpenMetrics histogram contract requires _bucket/_count/_sum to
+        # describe the same observation stream.
+        telemetry = tele._replace(stage_hist=tele.stage_hist.at[
+            bidx, hist_rows].add(jnp.where(succ_mask, 1, 0), mode="drop"))
 
-    # min-RT: stage one dense [R] min then fold into the current buckets.
-    num_rows = w1.num_rows
-    rt_obs = jnp.where((valid & batch.success)[:, None], rt4, W.MIN_RT_EMPTY)
-    mstage = jnp.full((num_rows,), W.MIN_RT_EMPTY, jnp.int32).at[
-        W.oob(rows4.reshape(-1), num_rows)
-    ].min(rt_obs.reshape(-1).astype(jnp.int32), mode="drop")
-    idx1 = W.current_index(now_ms, spec1)
-    w1 = w1._replace(min_rt=w1.min_rt.at[idx1].set(
-        jnp.minimum(w1.min_rt[idx1], mstage)))
-    sec = sec._replace(min_rt=jnp.minimum(sec.min_rt, mstage))
+    with jax.named_scope("commit"):
+        # min-RT: stage one dense [R] min then fold into the current buckets.
+        num_rows = w1.num_rows
+        rt_obs = jnp.where((valid & batch.success)[:, None], rt4, W.MIN_RT_EMPTY)
+        mstage = jnp.full((num_rows,), W.MIN_RT_EMPTY, jnp.int32).at[
+            W.oob(rows4.reshape(-1), num_rows)
+        ].min(rt_obs.reshape(-1).astype(jnp.int32), mode="drop")
+        idx1 = W.current_index(now_ms, spec1)
+        w1 = w1._replace(min_rt=w1.min_rt.at[idx1].set(
+            jnp.minimum(w1.min_rt[idx1], mstage)))
+        sec = sec._replace(min_rt=jnp.minimum(sec.min_rt, mstage))
 
-    # Clamp at zero: in a correct stream exits never outnumber entries,
-    # so a negative row only arises from unmatched exits after a cold
-    # state drop (DeviceDispatchError recovery) — without the clamp those
-    # stale handles would bias THREAD-grade admission permanently.
-    cur_threads = jnp.maximum(
-        state.cur_threads + extras[0].astype(jnp.int32), 0)
+        # Clamp at zero: in a correct stream exits never outnumber entries,
+        # so a negative row only arises from unmatched exits after a cold
+        # state drop (DeviceDispatchError recovery) — without the clamp those
+        # stale handles would bias THREAD-grade admission permanently.
+        cur_threads = jnp.maximum(
+            state.cur_threads + extras[0].astype(jnp.int32), 0)
 
-    degrade = D.feed_degrade(rules.degrade, state.degrade, batch, now_ms)
-    param = P.feed_param_exit(rules.param, state.param, batch)
+    with jax.named_scope("degrade"):
+        degrade = D.feed_degrade(rules.degrade, state.degrade, batch, now_ms)
+    with jax.named_scope("param"):
+        param = P.feed_param_exit(rules.param, state.param, batch)
 
     shadow = state.shadow
     if shadow_rules is not None and shadow is not None:
-        shadow = shadow._replace(
-            degrade=D.feed_degrade(shadow_rules.degrade, shadow.degrade,
-                                   batch, now_ms),
-            param=P.feed_param_exit(shadow_rules.param, shadow.param, batch),
-        )
+        with jax.named_scope("shadow"):
+            shadow = shadow._replace(
+                degrade=D.feed_degrade(shadow_rules.degrade, shadow.degrade,
+                                       batch, now_ms),
+                param=P.feed_param_exit(shadow_rules.param, shadow.param,
+                                        batch),
+            )
 
     return state._replace(w1=w1, w60=w60, cur_threads=cur_threads,
                           degrade=degrade, param=param, sec=sec,
