@@ -308,18 +308,44 @@ def _gather2(arr, r, s, fill):
     return jnp.where(ok, arr[jnp.where(ok, r, 0), s], jnp.asarray(fill, arr.dtype))
 
 
-def _cms_min(cms: jax.Array, srule: jax.Array, pos: jax.Array) -> jax.Array:
-    """min over depth of ``cms[rule, d, pos[:, d]]`` — the CMS estimate.
+def _cms_probe(cms: jax.Array, srule: jax.Array, pos: jax.Array) -> jax.Array:
+    """Depth-wise readings ``cms[rule, d, pos[:, d]]``, [N, D]. ``srule`` < 0
+    (no applicable rule) reads row 0."""
+    r = jnp.where(srule >= 0, srule, 0)
+    return cms[r[:, None], jnp.arange(cms.shape[1])[None, :], pos]
 
-    Depth comes from the table (the admission sketch is deeper than the
-    promotion sketch); ``pos`` columns beyond it are ignored. ``srule`` <
-    0 (no applicable rule) reads row 0 and is masked to 0.
-    """
-    d = cms.shape[1]
-    ok = (srule >= 0) & (srule < cms.shape[0])
-    r = jnp.where(ok, srule, 0)
-    vals = cms[r[:, None], jnp.arange(d)[None, :], pos[:, :d]]  # [N, d]
-    return jnp.where(ok, vals.min(axis=1), 0.0)
+
+def _cms_min(vals: jax.Array, srule: jax.Array) -> jax.Array:
+    """The CMS estimate from a probe's readings: their min over depth, 0
+    where no rule applies."""
+    return jnp.where(srule >= 0, vals.min(axis=1), 0.0)
+
+
+class _SlotRead(NamedTuple):
+    """What one rule slot's check reads from the rules and the state before
+    any survivor set is known (see ``_read_slot``)."""
+
+    srule: jax.Array       # int32[N] applicable rule id, -1 otherwise
+    applicable: jax.Array  # bool[N]
+    slot: jax.Array        # int32[N] bucket-table column
+    gid: jax.Array         # int32[N] (rule, slot) group for the prefixes
+    pv_hash: jax.Array     # uint32[N]
+    stored_key: jax.Array  # uint32[N] the slot's owner
+    fresh: jax.Array       # bool[N] value does not own its slot
+    thr: jax.Array         # float32[N]
+    is_thread: jax.Array   # bool[N]
+    is_rl: jax.Array       # bool[N]
+    windows: jax.Array     # int64[N] whole windows since the refill stamp
+    avail: jax.Array       # float32[N] tokens the value may take
+    gauge: jax.Array       # int32[N] thread gauge (0 when fresh)
+    latest: jax.Array      # int64[N] leaky-bucket head after the idle clamp
+    cost_us: jax.Array     # int64[N] per-token leaky-bucket cost
+    max_queue_us: jax.Array  # int64[N]
+    pos: jax.Array         # int32[N, D] sketch columns of the value
+    at_min: jax.Array      # bool[N, D] admission-sketch cells at their min
+    hot_min: jax.Array     # bool[N, D] promotion-sketch cells at their min
+    hot_est: jax.Array     # float32[N] promotion estimate of the value
+    owner_est: jax.Array   # float32[N] promotion estimate of the owner
 
 
 def check_param_flow(
@@ -342,6 +368,11 @@ def check_param_flow(
     the flow sweep: 32 tokens against a 9-token bucket). The final
     commit pass then evaluates + commits bucket state once.
 
+    Everything a sweep reads that does not depend on the survivor set —
+    rule vectors, bucket-table cells, sketch readings — is gathered once
+    per step (``_read_slot``), so the sweeps and the commit only add the
+    segmented prefix over the survivors.
+
     ``extra_cms`` (pod path): the psum of the OTHER devices' sketches.
     Sketch addition is the sketch of the union stream, so cluster-mode
     param rules admit every value against the POD-global window estimate —
@@ -352,16 +383,23 @@ def check_param_flow(
     # roll_sketch_windows; the pod wrapper also calls it BEFORE its psum so
     # the cross-device extra never carries a stale window).
     ps = roll_sketch_windows(rt, ps, now_ms)
+    # Every slot reads before any slot commits. Exact: compile_param_rules
+    # puts each rule at ONE (row, k), so the rule ids of slot k never occur
+    # at another slot, and slot k's commit writes only rows of the state
+    # that no other slot reads (lanes without a rule at k read row 0 but
+    # their readings are masked or their writes dropped).
+    reads = [_read_slot(rt, ps, batch, now_ms, candidate, k, extra_cms)
+             for k in range(rt.slots)]
 
     def _blocked_for(survivors):
-        return _eval_param(rt, ps, batch, now_ms, candidate,
-                           survivors=survivors, commit=False,
-                           extra_cms=extra_cms).blocked
+        blocked = jnp.zeros((batch.size,), bool)
+        for rd in reads:
+            ok, _ = _slot_ok(rd, batch, now_ms, survivors)
+            blocked = blocked | (rd.applicable & (~ok))
+        return blocked
 
     survivors = FX.survivor_fixpoint(candidate, _blocked_for, batch.count)
-    return _eval_param(rt, ps, batch, now_ms, candidate,
-                       survivors=survivors, commit=True,
-                       extra_cms=extra_cms)
+    return _commit(ps, reads, batch, now_ms, survivors)
 
 
 def roll_sketch_windows(rt: ParamRuleTensors, ps: ParamFlowState,
@@ -398,242 +436,271 @@ def roll_sketch_windows(rt: ParamRuleTensors, ps: ParamFlowState,
     return jax.lax.cond(jnp.any(rolled), _sweep, lambda p: p, ps)
 
 
-def _eval_param(
+def _read_slot(
     rt: ParamRuleTensors,
     ps: ParamFlowState,
     batch: EntryBatch,
     now_ms: jax.Array,
     candidate: jax.Array,
-    survivors: jax.Array,
-    commit: bool,
-    extra_cms: Optional[jax.Array] = None,
-) -> ParamVerdict:
+    k: int,
+    extra_cms: Optional[jax.Array],
+) -> _SlotRead:
+    """Rule slot ``k``'s survivor-independent reads: the rule, the value's
+    bucket cell, and the three sketch probes (admission sketch at the
+    value's columns, promotion sketch at the value's and at the owner's)."""
     n = batch.size
     table_slots = ps.key.shape[1]
+    rule_id = rt.rules_by_row.at[
+        W.oob(batch.cluster_row, rt.rules_by_row.shape[0]), jnp.full((n,), k)
+    ].get(mode="fill", fill_value=-1)
+    has_rule = rule_id >= 0
+    g = lambda a, fill=0: _gather1(a, rule_id, fill)
 
+    pidx = g(rt.param_idx)
+    pv_hash = jnp.take_along_axis(batch.param_hash, pidx[:, None], axis=1)[:, 0]
+    pv_present = jnp.take_along_axis(batch.param_present, pidx[:, None], axis=1)[:, 0]
+    applicable = has_rule & candidate & pv_present
+    # Cluster-mode param rules already enforced remotely are skipped
+    # (reference: ParamFlowChecker cluster branch replaces local check).
+    applicable = applicable & ~(g(rt.remote_mode, False) & batch.skip_cluster)
+
+    # Per-value exception items (exact hash match) override the rule count.
+    items_h = rt.item_hash.at[W.oob(rule_id, rt.num_rules)].get(
+        mode="fill", fill_value=0
+    )  # [N, MAX_ITEMS]
+    items_c = rt.item_count.at[W.oob(rule_id, rt.num_rules)].get(
+        mode="fill", fill_value=0.0
+    )
+    item_match = (items_h == pv_hash[:, None]) & (items_h != 0)
+    has_item = jnp.any(item_match, axis=1)
+    item_thr = jnp.max(jnp.where(item_match, items_c, -1.0), axis=1)
+    thr = jnp.where(has_item, item_thr, g(rt.threshold, 0.0))
+
+    slot = (pv_hash % jnp.uint32(table_slots)).astype(jnp.int32)
+    srule = jnp.where(applicable, rule_id, -1)
+    stored_key = _gather2(ps.key, srule, slot, 0)
+    fresh = (stored_key != pv_hash)  # empty or evicted -> full bucket
+
+    grade = g(rt.grade)
+    behavior = g(rt.behavior)
+    dur_ms = g(rt.duration_ms, 1000).astype(jnp.int64)
+    max_count = thr + g(rt.burst, 0.0)
+    is_thread = grade == C.PARAM_FLOW_GRADE_THREAD
+    is_rl = (~is_thread) & (behavior == C.CONTROL_BEHAVIOR_RATE_LIMITER)
+
+    # Group identity for within-batch sequencing: same (rule, slot).
+    gid = jnp.where(applicable, rule_id * table_slots + slot, -1)
+
+    # --- QPS / DEFAULT: windowed token bucket (passDefaultLocalCheck)
+    stored_tokens = _gather2(ps.tokens, srule, slot, 0.0)
+    filled = _gather2(ps.filled_ms, srule, slot, 0)
+    windows = jnp.maximum((now_ms.astype(jnp.int64) - filled) // jnp.maximum(dur_ms, 1), 0)
+    refilled = jnp.minimum(
+        stored_tokens + windows.astype(jnp.float32) * thr, max_count
+    )
+    # Cold tier: a key that does not own its slot admits against the
+    # CMS estimate of its own window usage (one-sided: est >= truth,
+    # so cold keys never over-admit). The hot owner keeps its exact
+    # bucket.
+    pos = _cms_positions(pv_hash)                    # [N, D]
+    cms_vals = _cms_probe(ps.cms, srule, pos)
+    est = _cms_min(cms_vals, srule)
+    avail = jnp.where(fresh, jnp.maximum(max_count - est, 0.0), refilled)
+    if extra_cms is not None:
+        # Pod path: cluster-mode param rules admit EVERY value (owner
+        # included) against the pod-global sketch — local + others'.
+        est_global = _cms_min(
+            cms_vals + _cms_probe(extra_cms, srule, pos), srule)
+        cm = g(rt.cluster_mode, False)
+        avail = jnp.where(cm, jnp.maximum(max_count - est_global, 0.0),
+                          avail)
+    # Promotion gate's two sides: BOTH probe the same promotion sketch
+    # (see _commit).
+    hot_vals = _cms_probe(ps.cms_hot, srule, pos)
+    owner_vals = _cms_probe(ps.cms_hot, srule, _cms_positions(stored_key))
+
+    # --- THREAD: concurrency gauge per value
+    gauge = _gather2(ps.threads, srule, slot, 0)
+    gauge = jnp.where(fresh, 0, gauge)
+
+    # --- RATE_LIMITER (passThrottleLocalCheck): per-value leaky bucket,
+    # cost = duration / threshold per token.
+    cost_us = jnp.where(
+        thr > 0,
+        (dur_ms.astype(jnp.float32) * 1000.0 / jnp.maximum(thr, 1e-9)),
+        jnp.float32(1e18),
+    ).astype(jnp.int64)
+    head0 = _gather2(ps.passed_us, srule, slot, 0)
+    head0 = jnp.where(fresh, 0, head0)
+    # Idle clamp scales with the acquire (whole multi-token acquire
+    # free after idle, like the reference — see flow.py's RL note).
+    now_us = now_ms.astype(jnp.int64) * 1000
+    latest = jnp.maximum(head0,
+                         now_us - cost_us * batch.count.astype(jnp.int64))
+
+    return _SlotRead(
+        srule=srule, applicable=applicable, slot=slot, gid=gid,
+        pv_hash=pv_hash, stored_key=stored_key, fresh=fresh, thr=thr,
+        is_thread=is_thread, is_rl=is_rl, windows=windows, avail=avail,
+        gauge=gauge, latest=latest, cost_us=cost_us,
+        max_queue_us=g(rt.max_queue_us, 0), pos=pos,
+        # Conservative update: only cells at the current minimum grow.
+        at_min=cms_vals <= cms_vals.min(axis=1, keepdims=True),
+        hot_min=hot_vals <= hot_vals.min(axis=1, keepdims=True),
+        hot_est=_cms_min(hot_vals, srule),
+        owner_est=_cms_min(owner_vals, srule))
+
+
+def _slot_ok(rd: _SlotRead, batch: EntryBatch, now_ms: jax.Array,
+             survivors: jax.Array):
+    """One sweep of one rule slot: the verdict of every lane given that
+    ``survivors`` admit. Returns ``(ok, rl_wait)``."""
+    acq = jnp.where(survivors & rd.applicable, batch.count, 0)
+    pre2, _ = segmented_prefix_dense(
+        rd.gid,
+        jnp.stack([acq, jnp.where(survivors & rd.applicable, 1, 0)], axis=1).astype(jnp.float32),
+    )
+    tok_prefix, ent_prefix = pre2[:, 0], pre2[:, 1]
+
+    acqf = batch.count.astype(jnp.float32)
+    qps_ok = (rd.thr > 0) & (tok_prefix + acqf <= rd.avail)
+    thread_ok = (rd.thr > 0) & (
+        rd.gauge.astype(jnp.float32) + ent_prefix + 1.0 <= rd.thr
+    )
+    now_us = now_ms.astype(jnp.int64) * 1000
+    expected = rd.latest + (tok_prefix + batch.count).astype(jnp.int64) * rd.cost_us
+    rl_wait = jnp.maximum(expected - now_us, 0)
+    rl_ok = (rd.thr > 0) & (rl_wait <= rd.max_queue_us)
+    ok = jnp.where(rd.is_thread, thread_ok, jnp.where(rd.is_rl, rl_ok, qps_ok))
+    return ok, rl_wait
+
+
+def _commit(
+    ps: ParamFlowState,
+    reads: List[_SlotRead],
+    batch: EntryBatch,
+    now_ms: jax.Array,
+    survivors: jax.Array,
+) -> ParamVerdict:
+    """Final sweep over the resolved survivors: verdicts, then each slot's
+    bucket, gauge, head and sketch updates."""
+    n = batch.size
     blocked = jnp.zeros((n,), bool)
     # First blocking rule slot per request (sequential chain's throw
     # site) for decision attribution; -1 while unblocked.
     first_slot = jnp.full((n,), -1, jnp.int32)
     wait_us = jnp.zeros((n,), jnp.int64)
-    now_us = now_ms.astype(jnp.int64) * 1000
+    acqf = batch.count.astype(jnp.float32)
+    pr = ps.key.shape[0]
 
-    for k in range(rt.slots):
-        rule_id = rt.rules_by_row.at[
-            W.oob(batch.cluster_row, rt.rules_by_row.shape[0]), jnp.full((n,), k)
-        ].get(mode="fill", fill_value=-1)
-        has_rule = rule_id >= 0
-        g = lambda a, fill=0: _gather1(a, rule_id, fill)
-
-        pidx = g(rt.param_idx)
-        pv_hash = jnp.take_along_axis(batch.param_hash, pidx[:, None], axis=1)[:, 0]
-        pv_present = jnp.take_along_axis(batch.param_present, pidx[:, None], axis=1)[:, 0]
-        applicable = has_rule & candidate & pv_present
-        # Cluster-mode param rules already enforced remotely are skipped
-        # (reference: ParamFlowChecker cluster branch replaces local check).
-        applicable = applicable & ~(g(rt.remote_mode, False) & batch.skip_cluster)
-
-        # Per-value exception items (exact hash match) override the rule count.
-        items_h = rt.item_hash.at[W.oob(rule_id, rt.num_rules)].get(
-            mode="fill", fill_value=0
-        )  # [N, MAX_ITEMS]
-        items_c = rt.item_count.at[W.oob(rule_id, rt.num_rules)].get(
-            mode="fill", fill_value=0.0
-        )
-        item_match = (items_h == pv_hash[:, None]) & (items_h != 0)
-        has_item = jnp.any(item_match, axis=1)
-        item_thr = jnp.max(jnp.where(item_match, items_c, -1.0), axis=1)
-        thr = jnp.where(has_item, item_thr, g(rt.threshold, 0.0))
-
-        slot = (pv_hash % jnp.uint32(table_slots)).astype(jnp.int32)
-        srule = jnp.where(applicable, rule_id, -1)
-        stored_key = _gather2(ps.key, srule, slot, 0)
-        fresh = (stored_key != pv_hash)  # empty or evicted -> full bucket
-
-        grade = g(rt.grade)
-        behavior = g(rt.behavior)
-        dur_ms = g(rt.duration_ms, 1000).astype(jnp.int64)
-        max_count = thr + g(rt.burst, 0.0)
-
-        # Group identity for within-batch sequencing: same (rule, slot).
-        gid = jnp.where(applicable, rule_id * table_slots + slot, -1)
-        acq = jnp.where(survivors & applicable, batch.count, 0)
-        pre2, _ = segmented_prefix_dense(
-            gid,
-            jnp.stack([acq, jnp.where(survivors & applicable, 1, 0)], axis=1).astype(jnp.float32),
-        )
-        tok_prefix, ent_prefix = pre2[:, 0], pre2[:, 1]
-
-        # --- QPS / DEFAULT: windowed token bucket (passDefaultLocalCheck)
-        stored_tokens = _gather2(ps.tokens, srule, slot, 0.0)
-        filled = _gather2(ps.filled_ms, srule, slot, 0)
-        windows = jnp.maximum((now_ms.astype(jnp.int64) - filled) // jnp.maximum(dur_ms, 1), 0)
-        refilled = jnp.minimum(
-            stored_tokens + windows.astype(jnp.float32) * thr, max_count
-        )
-        # Cold tier: a key that does not own its slot admits against the
-        # CMS estimate of its own window usage (one-sided: est >= truth,
-        # so cold keys never over-admit). The hot owner keeps its exact
-        # bucket.
-        pos = _cms_positions(pv_hash)                    # [N, D]
-        est = _cms_min(ps.cms, srule, pos)               # [N]
-        avail = jnp.where(fresh, jnp.maximum(max_count - est, 0.0), refilled)
-        if extra_cms is not None:
-            # Pod path: cluster-mode param rules admit EVERY value (owner
-            # included) against the pod-global sketch — local + others'.
-            est_global = _cms_min(ps.cms + extra_cms, srule, pos)
-            cm = g(rt.cluster_mode, False)
-            avail = jnp.where(cm, jnp.maximum(max_count - est_global, 0.0),
-                              avail)
-        acqf = batch.count.astype(jnp.float32)
-        qps_ok = (thr > 0) & (tok_prefix.astype(jnp.float32) + acqf <= avail)
-
-        # --- THREAD: concurrency gauge per value
-        gauge = _gather2(ps.threads, srule, slot, 0)
-        gauge = jnp.where(fresh, 0, gauge)
-        thread_ok = (thr > 0) & (
-            gauge.astype(jnp.float32) + ent_prefix.astype(jnp.float32) + 1.0 <= thr
-        )
-
-        # --- RATE_LIMITER (passThrottleLocalCheck): per-value leaky bucket,
-        # cost = duration / threshold per token.
-        cost_us = jnp.where(
-            thr > 0,
-            (dur_ms.astype(jnp.float32) * 1000.0 / jnp.maximum(thr, 1e-9)),
-            jnp.float32(1e18),
-        ).astype(jnp.int64)
-        head0 = _gather2(ps.passed_us, srule, slot, 0)
-        head0 = jnp.where(fresh, 0, head0)
-        # Idle clamp scales with the acquire (whole multi-token acquire
-        # free after idle, like the reference — see flow.py's RL note).
-        latest = jnp.maximum(head0,
-                             now_us - cost_us * batch.count.astype(jnp.int64))
-        expected = latest + (tok_prefix + batch.count).astype(jnp.int64) * cost_us
-        rl_wait = jnp.maximum(expected - now_us, 0)
-        rl_ok = (thr > 0) & (rl_wait <= g(rt.max_queue_us, 0))
-
-        is_thread = grade == C.PARAM_FLOW_GRADE_THREAD
-        is_rl = (~is_thread) & (behavior == C.CONTROL_BEHAVIOR_RATE_LIMITER)
-        ok = jnp.where(is_thread, thread_ok, jnp.where(is_rl, rl_ok, qps_ok))
-
+    for k, rd in enumerate(reads):
+        ok, rl_wait = _slot_ok(rd, batch, now_ms, survivors)
+        applicable, srule, slot, fresh = rd.applicable, rd.srule, rd.slot, rd.fresh
+        is_thread, is_rl = rd.is_thread, rd.is_rl
         slot_blocked = applicable & (~ok)
         first_slot = jnp.where(slot_blocked & (~blocked), k, first_slot)
         blocked = blocked | slot_blocked
         admitted = applicable & ok & survivors
         wait_us = jnp.maximum(wait_us, jnp.where(admitted & is_rl, rl_wait, 0))
 
-        if commit:
-            dflt = applicable & (~is_thread) & (~is_rl)
-            # Promotion gate (space-saving top-k): an admitted cold key
-            # takes the slot only when its window count has caught up with
-            # the owner's — a cold-key storm can't evict a hot key's exact
-            # bucket. Empty slots (key 0) are claimed directly. BOTH gate
-            # sides probe the same promotion sketch: symmetric
-            # collision inflation cancels in the comparison, which is what
-            # keeps a 100k-key cold storm from out-scoring a hot owner
-            # whose bar would otherwise stay at its small exact count.
-            hot_est = _cms_min(ps.cms_hot, srule, pos)
-            owner_est = _cms_min(ps.cms_hot, srule, _cms_positions(stored_key))
-            promoted = (admitted & dflt & fresh
-                        & ((stored_key == 0) | (hot_est + acqf >= owner_est)))
-            # THREAD / RATE_LIMITER keep direct eviction (no windowed CMS
-            # analog for gauges / leaky-bucket heads).
-            claim_other = (admitted | (applicable & fresh)) & (is_thread | is_rl)
-            claim = promoted | claim_other | (admitted & dflt & (~fresh))
-            ridx = W.oob(jnp.where(claim, srule, -1), ps.key.shape[0])
-            ps = ps._replace(
-                key=ps.key.at[ridx, slot].set(pv_hash, mode="drop"),
-            )
-            need_stamp = dflt & (~fresh) & (windows >= 1)
-            stamp = need_stamp | promoted | (claim_other & fresh)
+        dflt = applicable & (~is_thread) & (~is_rl)
+        # Promotion gate (space-saving top-k): an admitted cold key
+        # takes the slot only when its window count has caught up with
+        # the owner's — a cold-key storm can't evict a hot key's exact
+        # bucket. Empty slots (key 0) are claimed directly. BOTH gate
+        # sides probe the same promotion sketch: symmetric
+        # collision inflation cancels in the comparison, which is what
+        # keeps a 100k-key cold storm from out-scoring a hot owner
+        # whose bar would otherwise stay at its small exact count.
+        promoted = (admitted & dflt & fresh
+                    & ((rd.stored_key == 0) | (rd.hot_est + acqf >= rd.owner_est)))
+        # THREAD / RATE_LIMITER keep direct eviction (no windowed CMS
+        # analog for gauges / leaky-bucket heads).
+        claim_other = (admitted | (applicable & fresh)) & (is_thread | is_rl)
+        claim = promoted | claim_other | (admitted & dflt & (~fresh))
+        ridx = W.oob(jnp.where(claim, srule, -1), pr)
+        ps = ps._replace(
+            key=ps.key.at[ridx, slot].set(rd.pv_hash, mode="drop"),
+        )
+        need_stamp = dflt & (~fresh) & (rd.windows >= 1)
+        stamp = need_stamp | promoted | (claim_other & fresh)
 
-            # int64 scatter (emulated as hi/lo-u32 pairs on TPU — one of
-            # the measured top-3 step costs); only window-boundary
-            # crossings, promotions, and evictions stamp, so steady-state
-            # batches skip it entirely via the cond.
-            def _stamp_filled(filled_prev):
-                tidx = W.oob(jnp.where(stamp, srule, -1), ps.key.shape[0])
-                return filled_prev.at[tidx, slot].set(
-                    now_ms.astype(jnp.int64), mode="drop")
+        # int64 scatter (emulated as hi/lo-u32 pairs on TPU — one of
+        # the measured top-3 step costs); only window-boundary
+        # crossings, promotions, and evictions stamp, so steady-state
+        # batches skip it entirely via the cond.
+        def _stamp_filled(filled_prev):
+            tidx = W.oob(jnp.where(stamp, srule, -1), pr)
+            return filled_prev.at[tidx, slot].set(
+                now_ms.astype(jnp.int64), mode="drop")
 
-            ps = ps._replace(filled_ms=jax.lax.cond(
-                jnp.any(stamp), _stamp_filled, lambda f: f, ps.filled_ms))
-            # Default-mode token accounting: owners (and freshly promoted
-            # keys, seeded from the CMS-discounted level) get their bucket
-            # set, then every admitted acquire is subtracted. Non-promoted
-            # cold admits consume CMS only — they must not clobber the
-            # owner's bucket.
-            touch = dflt & ((~fresh) | promoted)
-            didx = W.oob(jnp.where(touch, srule, -1), ps.key.shape[0])
-            tokens = ps.tokens.at[didx, slot].set(avail, mode="drop")
-            tokens = tokens.at[
-                W.oob(jnp.where(admitted & touch, srule, -1), ps.key.shape[0]),
-                slot,
-            ].add(-acqf, mode="drop")
-            ps = ps._replace(tokens=jnp.maximum(tokens, 0.0))
-            # Every admitted default-grade acquire lands in the sketch (the
-            # owner's too, keeping owner_est honest for promotion races).
-            # Conservative update: only cells at the current minimum grow,
-            # which tightens the one-sided over-estimate for colliding keys
-            # (still never under-estimates).
-            cidx = W.oob(jnp.where(admitted & dflt, srule, -1), ps.key.shape[0])
-            r0 = jnp.where(srule >= 0, srule, 0)
-            darange = jnp.arange(CMS_DEPTH)[None, :]
-            depth_vals = ps.cms[r0[:, None], darange, pos]
-            at_min = depth_vals <= depth_vals.min(axis=1, keepdims=True)
-            inc = jnp.where((admitted & dflt)[:, None] & at_min, acqf[:, None], 0.0)
-            ps = ps._replace(cms=ps.cms.at[
-                cidx[:, None], darange, pos].add(inc, mode="drop"))
-            hot_vals = ps.cms_hot[r0[:, None], darange, pos]
-            hot_min = hot_vals <= hot_vals.min(axis=1, keepdims=True)
-            hot_inc = jnp.where((admitted & dflt)[:, None] & hot_min,
-                                acqf[:, None], 0.0)
-            ps = ps._replace(cms_hot=ps.cms_hot.at[
-                cidx[:, None], darange, pos].add(hot_inc, mode="drop"))
-            # Throttle-mode head advance: head' = latest + consumed · cost.
-            # Evicted slots first drop their stale head so .max starts fresh.
-            # The whole block rides a lax.cond: the head table is int64
-            # (epoch µs), whose scatter-set/-max lower to ~0.55ms/step of
-            # emulated hi/lo-u32 scatter fusions on TPU EACH — measured as
-            # the 3 hottest ops of the fused step — plus a dense-prefix
-            # scan. With no rate-limiter param traffic in the batch (the
-            # common case: QPS-reject param rules), every index is dropped
-            # and the state provably unchanged, so the cond skips it all.
-            def _advance_rl_heads(passed_prev):
-                fresh_rl = W.oob(
-                    jnp.where(applicable & is_rl & fresh, srule, -1),
-                    ps.key.shape[0])
-                passed = passed_prev.at[fresh_rl, slot].set(0, mode="drop")
-                rlidx = W.oob(jnp.where(admitted & is_rl, srule, -1),
-                              ps.key.shape[0])
-                consumed_after, _ = segmented_prefix_dense(
-                    gid,
-                    jnp.where(admitted & is_rl, batch.count, 0)
-                    .astype(jnp.float32))
-                last_total = consumed_after + jnp.where(
-                    admitted & is_rl, batch.count, 0)
-                new_head = latest + last_total.astype(jnp.int64) * cost_us
-                return passed.at[rlidx, slot].max(new_head, mode="drop")
+        ps = ps._replace(filled_ms=jax.lax.cond(
+            jnp.any(stamp), _stamp_filled, lambda f: f, ps.filled_ms))
+        # Default-mode token accounting: owners (and freshly promoted
+        # keys, seeded from the CMS-discounted level) get their bucket
+        # set, then every admitted acquire is subtracted. Non-promoted
+        # cold admits consume CMS only — they must not clobber the
+        # owner's bucket.
+        touch = dflt & ((~fresh) | promoted)
+        didx = W.oob(jnp.where(touch, srule, -1), pr)
+        tokens = ps.tokens.at[didx, slot].set(rd.avail, mode="drop")
+        tokens = tokens.at[
+            W.oob(jnp.where(admitted & touch, srule, -1), pr),
+            slot,
+        ].add(-acqf, mode="drop")
+        ps = ps._replace(tokens=jnp.maximum(tokens, 0.0))
+        # Every admitted default-grade acquire lands in the sketch (the
+        # owner's too, keeping owner_est honest for promotion races).
+        # Conservative update: only cells at the current minimum grow,
+        # which tightens the one-sided over-estimate for colliding keys
+        # (still never under-estimates).
+        cidx = W.oob(jnp.where(admitted & dflt, srule, -1), pr)
+        darange = jnp.arange(CMS_DEPTH)[None, :]
+        inc = jnp.where((admitted & dflt)[:, None] & rd.at_min, acqf[:, None], 0.0)
+        ps = ps._replace(cms=ps.cms.at[
+            cidx[:, None], darange, rd.pos].add(inc, mode="drop"))
+        hot_inc = jnp.where((admitted & dflt)[:, None] & rd.hot_min,
+                            acqf[:, None], 0.0)
+        ps = ps._replace(cms_hot=ps.cms_hot.at[
+            cidx[:, None], darange, rd.pos].add(hot_inc, mode="drop"))
+        # Throttle-mode head advance: head' = latest + consumed · cost.
+        # Evicted slots first drop their stale head so .max starts fresh.
+        # The whole block rides a lax.cond: the head table is int64
+        # (epoch µs), whose scatter-set/-max lower to ~0.55ms/step of
+        # emulated hi/lo-u32 scatter fusions on TPU EACH — measured as
+        # the 3 hottest ops of the fused step — plus a dense-prefix
+        # scan. With no rate-limiter param traffic in the batch (the
+        # common case: QPS-reject param rules), every index is dropped
+        # and the state provably unchanged, so the cond skips it all.
+        def _advance_rl_heads(passed_prev):
+            fresh_rl = W.oob(jnp.where(applicable & is_rl & fresh, srule, -1), pr)
+            passed = passed_prev.at[fresh_rl, slot].set(0, mode="drop")
+            rlidx = W.oob(jnp.where(admitted & is_rl, srule, -1), pr)
+            consumed_after, _ = segmented_prefix_dense(
+                rd.gid,
+                jnp.where(admitted & is_rl, batch.count, 0)
+                .astype(jnp.float32))
+            last_total = consumed_after + jnp.where(
+                admitted & is_rl, batch.count, 0)
+            new_head = rd.latest + last_total.astype(jnp.int64) * rd.cost_us
+            return passed.at[rlidx, slot].max(new_head, mode="drop")
 
-            ps = ps._replace(passed_us=jax.lax.cond(
-                jnp.any(applicable & is_rl), _advance_rl_heads,
-                lambda p: p, ps.passed_us))
+        ps = ps._replace(passed_us=jax.lax.cond(
+            jnp.any(applicable & is_rl), _advance_rl_heads,
+            lambda p: p, ps.passed_us))
 
-            # Thread gauge: reset evicted buckets, then increment admits.
-            # Same skip for batches with no thread-grade param traffic.
-            def _advance_threads(threads_prev):
-                thidx = W.oob(
-                    jnp.where(applicable & fresh & is_thread, srule, -1),
-                    ps.key.shape[0])
-                threads = threads_prev.at[thidx, slot].set(0, mode="drop")
-                return threads.at[
-                    W.oob(jnp.where(admitted & is_thread, srule, -1),
-                          ps.key.shape[0]), slot
-                ].add(1, mode="drop")
+        # Thread gauge: reset evicted buckets, then increment admits.
+        # Same skip for batches with no thread-grade param traffic.
+        def _advance_threads(threads_prev):
+            thidx = W.oob(jnp.where(applicable & fresh & is_thread, srule, -1), pr)
+            threads = threads_prev.at[thidx, slot].set(0, mode="drop")
+            return threads.at[
+                W.oob(jnp.where(admitted & is_thread, srule, -1), pr), slot
+            ].add(1, mode="drop")
 
-            ps = ps._replace(threads=jax.lax.cond(
-                jnp.any(applicable & is_thread), _advance_threads,
-                lambda t: t, ps.threads))
+        ps = ps._replace(threads=jax.lax.cond(
+            jnp.any(applicable & is_thread), _advance_threads,
+            lambda t: t, ps.threads))
 
     return ParamVerdict(blocked=blocked, wait_us=wait_us, state=ps,
                         slot=first_slot)
