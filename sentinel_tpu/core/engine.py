@@ -2418,6 +2418,28 @@ class SentinelEngine:
                 return None
             return np.asarray(st.shadow.counts)
 
+    def breaker_transitions(self) -> Dict:
+        """The live breakers' state-change record since their degrade
+        rules were last loaded (models/degrade.py ``DegradeState``):
+        ``rules``, the valid degrade rules in rule-id order, and per rule
+        ``toOpen`` / ``toHalfOpen`` / ``toClosed`` int64 counts of the
+        transitions into each state, ``lastOpenMs`` (last CLOSED -> OPEN)
+        and ``lastCloseMs`` (last HALF_OPEN -> CLOSED) clock readings, -1
+        where none happened. A read of device state: no dispatch."""
+        with self._lock:
+            self._ensure_compiled()
+            ds = self._state.degrade
+            rules = [r for r in self.degrade_rules.get_rules() if r.is_valid()]
+            transitions = np.asarray(ds.transitions)
+            opened = np.asarray(ds.last_open_ms)
+            closed = np.asarray(ds.last_close_ms)
+        n = len(rules)
+        return {"rules": rules,
+                "toOpen": transitions[D.TO_OPEN, :n],
+                "toHalfOpen": transitions[D.TO_HALF_OPEN, :n],
+                "toClosed": transitions[D.TO_CLOSED, :n],
+                "lastOpenMs": opened[:n], "lastCloseMs": closed[:n]}
+
     def telemetry_counts(self) -> Dict[str, np.ndarray]:
         """Cumulative device telemetry since engine start, as numpy:
         ``blockByReason`` int64[NUM_ATTR_REASONS, R] per-(reason family,

@@ -26,12 +26,30 @@ before the flip, matching the reference's observer behavior; CLOSED rules
 re-evaluate their threshold and may trip OPEN. With several completions of
 one HALF_OPEN rule in a batch, any bad outcome wins (the serial reference's
 final state depends on arrival order; documented delta).
+
+Trip comparison: upstream judges ``slowCount * 1.0d / totalCount >
+slowRatioThreshold`` (and the error ratio against ``count``) in Java
+doubles. The device never divides: each ratio threshold compiles on the
+host to the least fraction ``num / den`` (``den`` ≤ the largest window
+count) that a double quotient of window counts exceeds the threshold at,
+so ``bad * den >= num * total`` in int64 gives upstream's verdict for every
+pair of counts the window can hold, ties included
+(:func:`ratio_trip_fraction`, :func:`ratio_trips`).
+
+State-change record: each rule counts its transitions into OPEN,
+HALF_OPEN and CLOSED (``transitions``), and keeps the clock reading of its
+last trip (CLOSED → OPEN) and of its last close (HALF_OPEN → CLOSED) — the
+vectorised ``CircuitBreakerStateChangeObserver``. A probe reverted because
+a later slot blocked its request counts as HALF_OPEN → OPEN, as upstream's
+terminate hook notifies it. The record restarts with the rules' state when
+degrade rules are reloaded.
 """
 
 from __future__ import annotations
 
-import threading
+import math
 from dataclasses import dataclass
+from fractions import Fraction
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -53,6 +71,19 @@ CH_SLOW = 2
 NUM_CH = 3
 
 BREAKER_BUCKETS = 1  # tumbling statIntervalMs bucket (reference sampleCount=1)
+
+# Rows of DegradeState.transitions: the state a transition entered (the
+# record's updates stack their masks in this order).
+TO_OPEN = 0
+TO_HALF_OPEN = 1
+TO_CLOSED = 2
+NUM_TRANSITIONS = 3
+# Transient state of a probe reverted within check_degrade (never stored).
+_REVERTED = 3
+
+# The largest count a breaker's int32 window holds: every ratio the device
+# judges is k / n with 0 <= k <= n <= MAX_WINDOW_COUNT.
+MAX_WINDOW_COUNT = (1 << 31) - 1
 
 
 @dataclass
@@ -88,7 +119,8 @@ class DegradeRuleTensors(NamedTuple):
     resource_row: jax.Array    # int32[DR]
     grade: jax.Array           # int32[DR]
     threshold: jax.Array       # float32[DR] (max rt | ratio | count)
-    slow_ratio: jax.Array      # float32[DR]
+    trip_num: jax.Array        # int64[DR] ratio grades trip where
+    trip_den: jax.Array        # int64[DR]   bad * trip_den >= trip_num * total
     min_request: jax.Array     # int32[DR]
     time_window_ms: jax.Array  # int64[DR]
     rules_by_row: jax.Array    # int32[R, K] degrade-rule ids per resource row
@@ -106,6 +138,9 @@ class DegradeState(NamedTuple):
     state: jax.Array          # int32[DR] BREAKER_*
     next_retry_ms: jax.Array  # int64[DR]
     win: W.RowWindow          # [DR, 1, 3] per-rule statIntervalMs window
+    transitions: jax.Array    # int64[NUM_TRANSITIONS, DR] entries into each state
+    last_open_ms: jax.Array   # int64[DR] clock of the last CLOSED -> OPEN; -1 never
+    last_close_ms: jax.Array  # int64[DR] clock of the last HALF_OPEN -> CLOSED; -1 never
 
 
 def make_degrade_state(rt: DegradeRuleTensors, stat_interval_ms: np.ndarray) -> DegradeState:
@@ -115,7 +150,58 @@ def make_degrade_state(rt: DegradeRuleTensors, stat_interval_ms: np.ndarray) -> 
         state=jnp.zeros((dr,), jnp.int32),
         next_retry_ms=jnp.zeros((dr,), jnp.int64),
         win=W.make_row_window(dr, BREAKER_BUCKETS, NUM_CH, stat_interval_ms),
+        transitions=jnp.zeros((NUM_TRANSITIONS, dr), jnp.int64),
+        last_open_ms=jnp.full((dr,), -1, jnp.int64),
+        last_close_ms=jnp.full((dr,), -1, jnp.int64),
     )
+
+
+def _least_fraction_above(x: Fraction, n_max: int) -> Tuple[int, int]:
+    """The least p / q > x with 1 <= q <= n_max, for 0 <= x < 1: a
+    Stern-Brocot descent that keeps a/b <= x < c/d and takes each run of
+    same-side mediant steps at once."""
+    a, b, c, d = 0, 1, 1, 1
+    while True:
+        kr = min(math.floor((x * b - a) / (c - x * d)), (n_max - b) // d)
+        a, b = a + kr * c, b + kr * d
+        gap = x * b - a
+        kl = (n_max - d) // b
+        if gap > 0:
+            kl = min(kl, math.ceil((c - x * d) / gap) - 1)
+        c, d = c + kl * a, d + kl * b
+        if kr == 0 and kl == 0:
+            return c, d
+
+
+def ratio_trip_fraction(threshold: float, trips_at_one: bool) -> Tuple[int, int]:
+    """``(num, den)`` such that for window counts ``0 <= k <= n <=
+    MAX_WINDOW_COUNT``, ``k * den >= num * n`` exactly when upstream's
+    ``k * 1.0d / n > threshold`` holds in doubles.
+
+    A double quotient exceeds ``threshold`` exactly when the true ratio
+    exceeds the midpoint between ``threshold`` and the next double up
+    (no ratio of such counts equals that midpoint: its denominator is a
+    power of two past 2^53), so the answer is the least fraction above the
+    midpoint whose denominator a window count can reach. ``trips_at_one``
+    is the slow-ratio grade's rule that a ratio of 1.0 trips a threshold
+    of 1.0 (or more). Both numbers are at most ``MAX_WINDOW_COUNT``, so
+    either product fits in int64; ``(1, 0)`` never trips (a NaN threshold,
+    which no double exceeds)."""
+    if threshold < 0:
+        return 0, 1
+    if threshold >= 1.0:
+        return (1, 1) if trips_at_one else (1, 0)
+    if math.isnan(threshold):
+        return 1, 0
+    mid = (Fraction(threshold) + Fraction(math.nextafter(threshold, 2.0))) / 2
+    return _least_fraction_above(mid, MAX_WINDOW_COUNT)
+
+
+def ratio_trips(bad: jax.Array, total: jax.Array, num: jax.Array,
+                den: jax.Array) -> jax.Array:
+    """``bad / total > threshold`` as upstream's doubles decide it, for the
+    threshold compiled to ``(num, den)`` by :func:`ratio_trip_fraction`."""
+    return bad.astype(jnp.int64) * den >= num * total.astype(jnp.int64)
 
 
 def compile_degrade_rules(
@@ -129,7 +215,8 @@ def compile_degrade_rules(
     res_row = np.full(dr, -1, np.int32)
     grade = np.zeros(dr, np.int32)
     threshold = np.zeros(dr, np.float32)
-    slow_ratio = np.ones(dr, np.float32)
+    trip_num = np.ones(dr, np.int64)
+    trip_den = np.zeros(dr, np.int64)  # (1, 0): never trips
     min_request = np.full(dr, C.DEGRADE_DEFAULT_MIN_REQUEST_AMOUNT, np.int32)
     time_window_ms = np.zeros(dr, np.int64)
     stat_interval = np.zeros(dr, np.int64)  # 0 => unused row
@@ -140,7 +227,12 @@ def compile_degrade_rules(
         res_row[i] = row
         grade[i] = r.grade
         threshold[i] = r.count
-        slow_ratio[i] = r.slow_ratio_threshold
+        if r.grade == C.DEGRADE_GRADE_RT:
+            trip_num[i], trip_den[i] = ratio_trip_fraction(
+                float(r.slow_ratio_threshold), trips_at_one=True)
+        elif r.grade == C.DEGRADE_GRADE_EXCEPTION_RATIO:
+            trip_num[i], trip_den[i] = ratio_trip_fraction(
+                float(r.count), trips_at_one=False)
         min_request[i] = r.min_request_amount
         time_window_ms[i] = r.time_window * 1000
         stat_interval[i] = r.stat_interval_ms
@@ -164,7 +256,8 @@ def compile_degrade_rules(
         resource_row=jnp.asarray(res_row),
         grade=jnp.asarray(grade),
         threshold=jnp.asarray(threshold),
-        slow_ratio=jnp.asarray(slow_ratio),
+        trip_num=jnp.asarray(trip_num),
+        trip_den=jnp.asarray(trip_den),
         min_request=jnp.asarray(min_request),
         time_window_ms=jnp.asarray(time_window_ms),
         rules_by_row=jnp.asarray(rules_by_row),
@@ -238,13 +331,24 @@ def check_degrade(
     # never completes, so its breaker would be stuck HALF_OPEN forever.
     # Revert those to OPEN (retry time untouched → re-probe-eligible at
     # once), the vectorized analog of the reference's terminate-hook
-    # workaround for alibaba/Sentinel#1638.
+    # workaround for alibaba/Sentinel#1638. The revert marks its rules
+    # _REVERTED first, so the record below reads every transition off the
+    # rule axis: a rule OPEN before the batch and not after was probed
+    # (one probe per rule per batch), and a marked one was reverted.
     for pr in probe_rules:
         dead = jnp.where(blocked, pr, -1)
-        state = state.at[W.oob(dead, rt.num_rules)].set(C.BREAKER_OPEN, mode="drop")
+        state = state.at[W.oob(dead, rt.num_rules)].set(_REVERTED, mode="drop")
+    reverted = state == _REVERTED
+    probed = (ds.state == C.BREAKER_OPEN) & (state != C.BREAKER_OPEN)
+    state = jnp.where(reverted, C.BREAKER_OPEN, state)
+    # Rows TO_OPEN, TO_HALF_OPEN, TO_CLOSED: a dense add, no scatter.
+    transitions = ds.transitions + jnp.stack(
+        [reverted, probed, jnp.zeros_like(probed)]).astype(jnp.int64)
 
-    return DegradeVerdict(blocked=blocked, state=ds._replace(state=state),
-                          slot=first_slot)
+    return DegradeVerdict(
+        blocked=blocked,
+        state=ds._replace(state=state, transitions=transitions),
+        slot=first_slot)
 
 
 def feed_degrade(
@@ -317,27 +421,26 @@ def feed_degrade(
 
     # --- rule-axis transitions -------------------------------------------
     totals = W.row_window_totals(win, jnp.arange(rt.num_rules))  # [DR, 3]
-    total = totals[:, CH_TOTAL].astype(jnp.float32)
-    error = totals[:, CH_ERROR].astype(jnp.float32)
-    slowc = totals[:, CH_SLOW].astype(jnp.float32)
-    enough = totals[:, CH_TOTAL] >= rt.min_request
+    total = totals[:, CH_TOTAL]
+    error = totals[:, CH_ERROR]
+    enough = total >= rt.min_request
 
-    # Strictly-greater comparisons per the reference breakers; the slow-call
-    # breaker additionally trips at ratio == threshold when threshold is 1.0
-    # (a 100% threshold would otherwise never fire).
-    ratio_den = jnp.maximum(total, 1.0)
-    slow_r = slowc / ratio_den
-    err_r = error / ratio_den
-    trip_slow = (slow_r > rt.slow_ratio) | ((rt.slow_ratio >= 1.0) & (slow_r >= 1.0))
-    trip = jnp.where(rt.grade == C.DEGRADE_GRADE_RT, trip_slow, err_r > rt.threshold)
-    trip = jnp.where(rt.grade == C.DEGRADE_GRADE_EXCEPTION_COUNT, error > rt.threshold, trip)
+    # Ratio grades: upstream's strictly-greater double comparison, decided
+    # exactly in integers (ratio_trip_fraction); the slow-call grade's
+    # threshold of 1.0 also trips at a ratio of 1.0. The count grade
+    # compares integer counts, exact in float32 below 2^24.
+    bad = jnp.where(rt.grade == C.DEGRADE_GRADE_RT, totals[:, CH_SLOW], error)
+    trip = ratio_trips(bad, total, rt.trip_num, rt.trip_den)
+    trip = jnp.where(rt.grade == C.DEGRADE_GRADE_EXCEPTION_COUNT,
+                     error.astype(jnp.float32) > rt.threshold, trip)
     trip = trip & enough
 
     is_closed = state == C.BREAKER_CLOSED
     is_half = state == C.BREAKER_HALF_OPEN
 
     # HALF_OPEN verdict: bad wins over good.
-    to_open = (is_closed & trip) | (is_half & half_bad)
+    tripped = is_closed & trip
+    to_open = tripped | (is_half & half_bad)
     to_closed = is_half & half_good & (~half_bad)
 
     state = jnp.where(to_open, C.BREAKER_OPEN, state)
@@ -348,4 +451,9 @@ def feed_degrade(
     win = win._replace(
         counts=jnp.where(to_closed[:, None, None], 0, win.counts)
     )
-    return DegradeState(state=state, next_retry_ms=next_retry, win=win)
+    return DegradeState(
+        state=state, next_retry_ms=next_retry, win=win,
+        transitions=ds.transitions + jnp.stack(
+            [to_open, jnp.zeros_like(to_open), to_closed]).astype(jnp.int64),
+        last_open_ms=jnp.where(tripped, now_ms, ds.last_open_ms),
+        last_close_ms=jnp.where(to_closed, now_ms, ds.last_close_ms))

@@ -103,6 +103,16 @@ def render_engine_metrics(engine) -> str:
                     [float(x) for x in buckets],
                     float(totals[int(C.MetricEvent.RT), row]))
 
+    bt = engine.breaker_transitions()
+    b.family("sentinel_tpu_breaker_transitions", "counter",
+             "Circuit-breaker state changes of the live degrade rules since "
+             "they were loaded, by the state entered (a reverted probe "
+             "counts as a change to open)")
+    for to, key in (("open", "toOpen"), ("half_open", "toHalfOpen"),
+                    ("closed", "toClosed")):
+        b.sample("sentinel_tpu_breaker_transitions_total", {"to": to},
+                 int(bt[key].sum()))
+
     # -- degradation channels (resilience_stats parity) -------------------
     res_stats = engine.resilience_stats()
     b.counter("sentinel_tpu_fail_open",
