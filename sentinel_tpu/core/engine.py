@@ -78,6 +78,10 @@ from sentinel_tpu.utils.param_hash import hash_param as _hash_param
 # shared by __init__ and reset_slot_floor so the two can't drift.
 INITIAL_SLOT_FLOOR = {"flow": 1, "degrade": 0, "authority": 0, "param": 0}
 
+# Widths of the row window read (row_window): a sampled batch's distinct
+# rows, padded up to the next width; more than the last is read in chunks.
+ROW_READ_WIDTHS = (8, 64, 512)
+
 
 class EntryHandle:
     """A live entry (reference: ``CtEntry``). Use as a context manager."""
@@ -733,6 +737,20 @@ class SentinelEngine:
             * qps_scale,
             st_.cur_threads))
 
+        # A def, not a jitted functools.partial: XLA names partials
+        # ``jit__unknown(...)``, the name the fused steps' device-time
+        # reading keys on.
+        def row_window_read(w1, cur_threads, now, rows):
+            # Gather, then rotate: the rotation's bucket mask and the
+            # bucket sum act on each row alone, so a gathered row reads
+            # what the whole-window read gives it.
+            win = W_.Window(w1.counts[:, :, rows], w1.min_rt[:, rows],
+                            w1.starts)
+            return (W_.all_totals(W_.rotate(win, now, spec1))
+                    .astype(jnp.float32) * qps_scale, cur_threads[rows])
+
+        self._row_read_jit = jax.jit(row_window_read)
+
     def _rebuild_entry_jit(self):
         # Version BEFORE checkers: a registration racing between the two
         # reads then leaves version != snapshot and the next
@@ -1039,7 +1057,8 @@ class SentinelEngine:
 
     def warmup(self, widths: Optional[Sequence[int]] = None) -> None:
         """Precompile the fused entry/exit steps for every micro-batch
-        ladder width under the CURRENT rule shapes.
+        ladder width under the CURRENT rule shapes, and the row window
+        read (:meth:`row_window`) at each of its widths.
 
         XLA specializes per (batch width, rule-tensor shape); the first
         dispatch of each pair pays a compile (seconds on CPU, 20-40s on
@@ -1056,6 +1075,8 @@ class SentinelEngine:
             xbuf = make_exit_batch_np(int(width))
             self._run_exit_batch(
                 ExitBatch(**{k: jnp.asarray(v) for k, v in xbuf.items()}))
+        for width in ROW_READ_WIDTHS:  # the decision-trace window reads
+            self.row_window(np.zeros(width, np.int64))
 
     def set_occupy_timeout(self, timeout_ms: int) -> None:
         """Retune the prioritized-borrow wait cap at runtime (reference:
@@ -2808,6 +2829,39 @@ class SentinelEngine:
             totals, threads = self._w1_read_jit(
                 self._state, jnp.asarray(now, jnp.int64))
             return np.asarray(totals), np.asarray(threads)
+
+    def row_window(self, rows):
+        """``row_stats()`` for the given device rows alone: (per-second
+        QPS totals f32[K, E], threads int[K]) as numpy, in ``rows``'
+        order, duplicates included, at this call's clock reading.
+
+        Gathers the rows on the device, in chunks padded to
+        :data:`ROW_READ_WIDTHS` (each compiled by :meth:`warmup`). Only
+        the dispatch holds the engine lock; the readback waits outside
+        it, so a caller's next step is not held behind the transfer (a
+        step that donates the state lets a pending read of it finish).
+        A row outside ``[0, capacity)`` raises ``IndexError``.
+        """
+        rows = np.asarray(rows, np.int64).reshape(-1)
+        if rows.size and (rows.min() < 0 or rows.max() >= self.capacity):
+            raise IndexError(
+                f"device rows must lie in [0, {self.capacity})")
+        top = ROW_READ_WIDTHS[-1]
+        reads = []
+        self._flush_committer()
+        with self._lock:
+            self._ensure_compiled()
+            now = jnp.asarray(self.now_ms(), jnp.int64)
+            state = self._state
+            for lo in range(0, max(rows.size, 1), top):
+                chunk = rows[lo:lo + top]
+                width = next(w for w in ROW_READ_WIDTHS if w >= chunk.size)
+                idx = np.zeros(width, np.int32)  # pad with row 0
+                idx[:chunk.size] = chunk
+                reads.append((chunk.size, self._row_read_jit(
+                    state.w1, state.cur_threads, now, idx)))
+        return (np.concatenate([np.asarray(t)[:n] for n, (t, _) in reads]),
+                np.concatenate([np.asarray(h)[:n] for n, (_, h) in reads]))
 
     def tree_dict(self) -> Dict:
         """Call tree rooted at machine-root (command API ``jsonTree``/``tree``).

@@ -45,6 +45,9 @@ ADMIT_DECISION_TRACES = "sentinel.admit.decision_traces"  # traces.submit
 ADMIT_POPULATION = "sentinel.admit.population"          # _observe_population
 ADMIT_COMPLETE_BATCH = "sentinel.admit.complete_batch"  # all of complete_batch
 ADMIT_EXIT_DISPATCH = "sentinel.admit.exit_dispatch"    # the exit jit call
+# Decision-trace worker (telemetry/trace_ring.py): the sampled rows' window
+# read, dispatch and readback.
+TRACES_WINDOW_READ = "sentinel.traces.window_read"
 # Wire frontend (cluster/reactor.py).
 REACTOR_READ = "sentinel.reactor.read"
 REACTOR_SUBMIT = "sentinel.reactor.submit"

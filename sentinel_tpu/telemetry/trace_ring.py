@@ -13,8 +13,9 @@ design, and the drop is counted); a
 daemon worker materializes them (``np.asarray`` blocks on the transfer
 in ITS thread, never the step stream), subsamples blocked lanes at the
 configured cadence, resolves node rows to names through the registry,
-and snapshots the blocked rows' instant window. Served by the ``traces``
-ops command and the dashboard.
+and snapshots the sampled rows' instant window (``engine.row_window``:
+those rows alone, read back outside the engine lock). Served by the
+``traces`` ops command and the dashboard.
 """
 
 from __future__ import annotations
@@ -33,6 +34,7 @@ from sentinel_tpu.core.config import (
     TELEMETRY_TRACE_CAPACITY,
     TELEMETRY_TRACE_SAMPLE_EVERY,
 )
+from sentinel_tpu.metrics.profiling import TRACES_WINDOW_READ, span
 from sentinel_tpu.telemetry.attribution import encode_reason_code
 
 
@@ -67,6 +69,8 @@ class DecisionTraceBuffer:
         self._error_logged_ms = 0.0
         self._seen_blocked = 0
         self._recorded = 0
+        self._window_reads = 0
+        self._window_rows = 0
         self._worker: Optional[threading.Thread] = None
         self._stop = threading.Event()
         # stop() is terminal until start(): a submit racing (or
@@ -189,26 +193,27 @@ class DecisionTraceBuffer:
         blocked_idx = np.nonzero(reasons > 0)[0]
         if blocked_idx.size == 0:
             return
+        n = self.sample_every
+        with self._lock:
+            seen = self._seen_blocked
+            self._seen_blocked = seen + blocked_idx.size
+        # The k-th blocked entry here (k from 1) is the (seen + k)-th
+        # ever seen; every n-th of those is picked.
+        picked = blocked_idx[n - 1 - seen % n::n]
+        if picked.size == 0:
+            return
         slots = np.asarray(decisions.rule_slot)
         rows = np.asarray(batch.cluster_row)
         origin_rows = np.asarray(batch.origin_row)
         counts = np.asarray(batch.count)
         entry_in = np.asarray(batch.entry_in)
-        picked = []
-        with self._lock:
-            for i in blocked_idx.tolist():
-                self._seen_blocked += 1
-                if self._seen_blocked % self.sample_every == 0:
-                    picked.append(i)
-        if not picked:
-            return
-        window = self._window_snapshot([int(rows[i]) for i in picked])
+        window = self._window_snapshot(rows[picked])
         # Device-row view: in slot mode this is CURRENT tenancy — a
         # trace materializing across an eviction may name the successor
         # (documented bounded race; the flight-recorder history is the
         # leak-proof surface, via per-stamp tenancy snapshots).
         metas = self.engine._device_metas()
-        for i in picked:
+        for i in picked.tolist():
             row = int(rows[i])
             orow = int(origin_rows[i])
             reason = int(reasons[i])
@@ -232,24 +237,30 @@ class DecisionTraceBuffer:
                 self._ring.append(trace)
                 del self._ring[:-self.capacity]
 
-    def _window_snapshot(self, rows: List[int]) -> Dict[int, Dict]:
-        """Instant-window view of the blocked rows at trace time — one
-        jitted read per sampled batch, amortized by the sampling cadence."""
+    def _window_snapshot(self, rows) -> Dict[int, Dict]:
+        """Instant-window view of the sampled rows at trace time — one
+        jitted read of those rows alone per sampled batch; rows outside
+        the device tensor are skipped."""
+        rows = np.unique(np.asarray(rows, np.int64))
+        rows = rows[(rows >= 0) & (rows < self.engine.capacity)]
+        if rows.size == 0:
+            return {}
         try:
-            totals, threads = self.engine.row_stats()
+            with span(TRACES_WINDOW_READ):
+                totals, threads = self.engine.row_window(rows)
         except Exception:
             return {}
-        out: Dict[int, Dict] = {}
-        for row in set(rows):
-            if not 0 <= row < totals.shape[0]:
-                continue
-            t = totals[row]
-            out[row] = {
+        with self._lock:
+            self._window_reads += 1
+            self._window_rows += int(rows.size)
+        return {
+            row: {
                 "passQps": round(float(t[C.MetricEvent.PASS]), 2),
                 "blockQps": round(float(t[C.MetricEvent.BLOCK]), 2),
-                "curThreadNum": int(threads[row]),
+                "curThreadNum": int(th),
             }
-        return out
+            for row, t, th in zip(rows.tolist(), totals, threads)
+        }
 
     # -- read side --------------------------------------------------------
 
@@ -263,6 +274,7 @@ class DecisionTraceBuffer:
         with self._lock:
             traces = list(self._ring)
             seen, recorded = self._seen_blocked, self._recorded
+            reads, read_rows = self._window_reads, self._window_rows
         traces = page_newest_first(traces, limit, offset)
         traces.reverse()  # newest first
         return {
@@ -271,6 +283,8 @@ class DecisionTraceBuffer:
             "seenBlocked": seen,
             "recorded": recorded,
             "droppedBatches": self._dropped,
+            "windowReads": reads,
+            "windowRows": read_rows,
             "errors": self._errors,
             "traces": traces,
         }
